@@ -35,24 +35,17 @@ def _find_tcmalloc() -> str:
 
 
 def setup_harness() -> str:
-    """Process-level perf harness: allocator + XLA CPU flags.
+    """Process-level perf harness: the allocator.
 
-    Two environment wins measured on the vgg9 im2col grad stack (see
-    DESIGN.md §11): disabling XLA:CPU's thunk runtime (~11% on the
-    benchmark hot loop) and preloading tcmalloc when the box has it
-    (absent here — the glob then no-ops).  Must run BEFORE jax (or
-    anything importing jax) initializes, which is why this module calls
-    it at the very top.  ``REPRO_HARNESS=0`` opts out entirely so the
-    same drivers can measure the un-harnessed baseline; the returned
+    Preloads tcmalloc when the machine has it (the glob no-ops
+    otherwise).  Must run BEFORE jax (or anything importing jax)
+    initializes, which is why this module calls it at the very top.
+    ``REPRO_HARNESS=0`` opts out entirely so the same drivers can
+    measure the un-harnessed baseline; the returned
     state ("on"/"off") is recorded in every trajectory-CSV row.
     """
     if os.environ.get("REPRO_HARNESS", "1") == "0":
         return "off"
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_cpu_use_thunk_runtime" not in flags:
-        os.environ["XLA_FLAGS"] = (
-            flags + " --xla_cpu_use_thunk_runtime=false"
-        ).strip()
     lib = _find_tcmalloc()
     if lib and lib not in os.environ.get("LD_PRELOAD", ""):
         if os.environ.get("_REPRO_REEXEC") != "1":
@@ -74,7 +67,7 @@ HARNESS = setup_harness()
 from repro.utils.cache import enable_compilation_cache  # noqa: E402
 
 # every figure run compiles the same small executables; cache them on disk
-# so repeated runs skip compilation (REPRO_JAX_CACHE overrides the path)
+# so repeated runs skip compilation (JAX_COMPILATION_CACHE_DIR places it)
 enable_compilation_cache()
 
 from repro.api import ExperimentSpec, Session  # noqa: E402

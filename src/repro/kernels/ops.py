@@ -8,8 +8,9 @@ fall back to a jnp formulation.  All five wrappers resolve their
 - ``"auto"``  — native kernel on TPU; off-TPU the *fallback* (the jnp
   oracle, or a faster jnp formulation where one exists — e.g. the
   im2col conv, since interpret-mode Pallas is for validation only);
-- ``"kernel"`` / ``"interpret"`` — the Pallas kernel (interpret mode is
-  forced off-TPU either way);
+- ``"kernel"`` — the native Pallas kernel; raises off-TPU, so a run
+  that asked for the compiled kernel never passes without one;
+- ``"interpret"`` — the Pallas kernel in interpret mode, on any backend;
 - ``"ref"`` — the jnp oracle from `kernels.ref`;
 - per-op extras (``batched_conv`` accepts ``"im2col"``).
 """
@@ -43,9 +44,14 @@ def dispatch(impl: str, *, ref, kernel, fallback=None, extra=None):
         return extra[impl]
     if impl == "ref" or (impl == "auto" and not _on_tpu()):
         return fallback if fallback is not None else ref
-    if impl in ("auto", "kernel", "interpret"):
-        interpret = (impl == "interpret") or not _on_tpu()
-        return functools.partial(kernel, interpret=interpret)
+    if impl == "interpret":
+        return functools.partial(kernel, interpret=True)
+    if impl in ("auto", "kernel"):
+        if not _on_tpu():
+            raise ValueError(
+                f"impl='kernel' needs a TPU backend, found "
+                f"{jax.default_backend()!r}; ask for 'interpret' by name")
+        return functools.partial(kernel, interpret=False)
     raise ValueError(f"unknown kernel impl {impl!r}")
 
 
@@ -86,9 +92,10 @@ def batched_conv(x, w, b, *, stride: int = 1, impl: str = "auto"):
     vmapped ``lax.conv`` oracle (autodiff-native, bitwise vs the
     per-client model path); every other impl routes forward AND backward
     through `batched_conv.conv_vjp`'s custom_vjp — the Pallas blocked
-    matmul on TPU (``kernel``/``interpret``), the jnp einsum matmul on
-    CPU (``im2col``, which is also what ``auto`` picks off-TPU: it
-    sidesteps XLA CPU's grouped-conv lowering, ~15x on the vgg9 grad).
+    matmul (``kernel`` natively on TPU, ``interpret`` anywhere), the
+    jnp einsum matmul on CPU (``im2col``, which is also what ``auto``
+    picks off-TPU: it sidesteps XLA CPU's grouped-conv lowering, ~15x
+    on the vgg9 grad).
     """
     im2col = BC.conv_vjp(stride, "einsum", False)
 

@@ -10,7 +10,6 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.dist.sharding import client_axis_spec
@@ -62,11 +61,11 @@ def make_sharded_scan(sim, mesh: Mesh, axis: str):
 
     def wrapped(stacked, t0, idx_seg, row_mask, masks, arrays, parts=None):
         pspec = None if parts is None else P(None, axis)
-        fn = shard_map(
+        fn = jax.shard_map(
             sim._scan_segment, mesh=mesh,
             in_specs=(sspecs, P(), P(None, axis), P(axis), P(), rep, pspec),
             out_specs=(sspecs, P(None, axis)),
-            check_rep=False)
+            check_vma=False)
         return fn(stacked, t0, idx_seg, row_mask, masks, arrays, parts)
 
     return jax.jit(wrapped, donate_argnums=(0,))

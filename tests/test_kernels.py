@@ -199,6 +199,25 @@ def test_clip_sgd_participation_interpret_vs_ref():
                                        rtol=2e-6, atol=2e-6)
 
 
+def test_ops_dispatch_kernel_needs_tpu():
+    """``impl="kernel"`` asks for the native kernel: off-TPU it raises
+    instead of quietly running interpret mode; interpret mode runs only
+    when asked for by name."""
+    from repro.kernels import ops
+    if jax.default_backend() == "tpu":
+        pytest.skip("the native kernel is available on this backend")
+    x, wt, bias, stride = _conv_operands(CONV_CASES[0])
+    with pytest.raises(ValueError, match="TPU"):
+        ops.batched_conv(x, wt, bias, stride=stride, impl="kernel")
+    with pytest.raises(ValueError, match="TPU"):
+        ops.clip_sgd(x[:, 0, 0], x[:, 0, 0], bias[:, 0],
+                     jnp.ones((x.shape[0],), bool), gamma=0.1,
+                     impl="kernel")
+    out = ops.batched_conv(x, wt, bias, stride=stride, impl="interpret")
+    assert out.shape == REF.batched_conv_ref(x, wt, bias,
+                                             stride=stride).shape
+
+
 def test_ops_dispatch_rejects_unknown_impl():
     from repro.kernels import ops
     x, wt, bias, stride = _conv_operands(CONV_CASES[0])

@@ -2,8 +2,10 @@
 
 The scan engine must be *equivalent* to the per-round vectorized engine —
 identical host-RNG sampling (bitwise), identical update algebra — with
-only ulp-level float differences allowed (the fused segment executable may
+only float differences allowed (the fused segment executable may
 reassociate reductions differently from the standalone round executable).
+A round's reassociation difference is an ulp or two, but a later round can
+amplify it: see `_assert_param_close_scaled`.
 """
 import jax
 import jax.numpy as jnp
@@ -21,10 +23,12 @@ from repro.models import build_model
 TIGHT = dict(rtol=1e-5, atol=1e-6)
 
 
-def _make_sim(engine, n=4, agg=3, seed_data=3, **kw):
+def _make_sim(engine, n=4, agg=3, seed_data=3, f64=False, **kw):
     cfg = get_config("vgg9-cifar-small")
     model = build_model(cfg)
     (xtr, ytr), (xte, yte) = make_cifar_like(10, 240, 60, 32, seed=seed_data)
+    if f64:
+        xtr, xte = xtr.astype(np.float64), xte.astype(np.float64)
     shards = partition_iid(len(ytr), n, np.random.default_rng(1))
     sampler = ClientSampler({"images": xtr, "labels": ytr}, shards,
                             np.random.default_rng(2))
@@ -41,6 +45,32 @@ def _assert_param_close(sim_a, sim_b):
                         jax.tree_util.tree_leaves(u_b)):
             np.testing.assert_allclose(np.asarray(x, np.float32),
                                        np.asarray(y, np.float32), **TIGHT)
+
+
+def _assert_param_close_scaled(sim_a, sim_b):
+    """Parameters equal to ``2**-16`` of the model's largest weight.
+
+    The engines run the same algebra (in float64 they agree to about
+    1e-10 — `test_scan_matches_vectorized_in_float64`), but in float32
+    XLA fuses the scan body and the standalone round executable
+    differently, and even the per-round step differs from its own eager
+    execution by about one ulp.  That gap stays at ulp level round after
+    round until it flips a discrete choice — a max-pool winner or a ReLU
+    sign — for some sample; the gradient then routes differently and the
+    client-side weights that client trains move by a fraction of one
+    ``lr`` step.  Such a step is set by the gradient, not by the leaf's
+    own size (biases start at zero), so the bound scales with the model's
+    weight magnitude: ``2**-16`` of it is 128-256 float32 ulps of the
+    largest weight, about five times the gap six rounds leave here
+    (2.3e-6 on a conv weight, against a bound of 1.1e-5).
+    """
+    ref = [np.asarray(x, np.float32) for x in
+           jax.tree_util.tree_leaves(sim_b.client_units[0])]
+    got = [np.asarray(x, np.float32) for x in
+           jax.tree_util.tree_leaves(sim_a.client_units[0])]
+    atol = 2.0 ** -16 * max(float(np.abs(x).max()) for x in ref)
+    for x, y in zip(got, ref):
+        np.testing.assert_allclose(x, y, rtol=TIGHT["rtol"], atol=atol)
 
 
 def test_host_rng_stream_identical():
@@ -61,9 +91,10 @@ def test_host_rng_stream_identical():
 
 def test_scan_matches_vectorized_across_eval_boundaries():
     """Multiple eval boundaries (multiple segments) plus mid-segment
-    every-I aggregation rounds: metrics and final parameters must match
-    the per-round vectorized engine to ulp level, the simulated clock
-    and sampling exactly."""
+    every-I aggregation rounds: metrics must match the per-round
+    vectorized engine at fp32 tolerance, final parameters within the
+    weight-scaled bound of `_assert_param_close_scaled`, the simulated
+    clock and sampling exactly."""
     def policy(s, rng):
         return np.full(s.n, 8), np.full(s.n, 3)
 
@@ -81,7 +112,32 @@ def test_scan_matches_vectorized_across_eval_boundaries():
                                res["vectorized"].test_loss, **TIGHT)
     np.testing.assert_allclose(res["scan"].test_acc,
                                res["vectorized"].test_acc, atol=1e-6)
-    _assert_param_close(sims["scan"], sims["vectorized"])
+    _assert_param_close_scaled(sims["scan"], sims["vectorized"])
+
+
+def test_scan_matches_vectorized_in_float64():
+    """The float32 gap above is rounding, not a difference between the
+    engines: the same six rounds in float64 leave the final parameters
+    equal to 1e-8 (what is left comes from the float32 clip norm both
+    engines share)."""
+    def policy(s, rng):
+        return np.full(s.n, 8), np.full(s.n, 3)
+
+    prev = jax.config.jax_enable_x64
+    jax.config.update("jax_enable_x64", True)
+    try:
+        sims = {}
+        for eng in ("vectorized", "scan"):
+            sim = _make_sim(eng, agg=3, f64=True)
+            sim.run(policy, rounds=6, eval_every=2)
+            sims[eng] = sim
+        for x, y in zip(jax.tree_util.tree_leaves(sims["scan"]._stacked),
+                        jax.tree_util.tree_leaves(sims["vectorized"]._stacked)):
+            assert x.dtype == jnp.float64
+            np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                       rtol=0, atol=1e-8)
+    finally:
+        jax.config.update("jax_enable_x64", prev)
 
 
 def test_scan_mid_segment_aggregation_schedule():
