@@ -51,6 +51,7 @@ import numpy as np
 
 from repro.core import split as SP
 from repro.core.sfl import SimResult, pow2_bucket
+from repro.utils.trace import span
 
 
 def group_cells(specs) -> list:
@@ -182,6 +183,23 @@ def run_group(sessions, *, verbose: bool = False) -> list:
             jnp.stack(parts) if faulty else None,
         )
 
+    def dispatch(carry, members, t, nxt, b_pad):
+        """One vmapped segment dispatch for a bucket's member cells:
+        plan, call and loss fetch, each in its span."""
+        seg = nxt - t
+        with span("plan", t=t):
+            idx, rmask, masks, parts = plans(members, t, nxt, b_pad)
+        rows = seg * sum(int(np.sum(decisions[g][0])) for g in members)
+        with span("dispatch", t=t, rows=rows,
+                  padded_rows=seg * len(members) * sim0.n * b_pad):
+            carry, losses = grid_fn(
+                carry, jnp.asarray(t, jnp.int32), idx, rmask, masks,
+                arrays_for(members), parts
+            )
+        with span("fetch", t=t):
+            losses = np.asarray(losses)
+        return carry, losses
+
     t = 0
     while t < rounds:
         nxt = min(
@@ -189,61 +207,58 @@ def run_group(sessions, *, verbose: bool = False) -> list:
             (t // reconf + 1) * reconf,
             rounds,
         )
-        t0 = jnp.asarray(t, jnp.int32)
-        buckets = {}
-        for g, (b, _) in enumerate(decisions):
-            buckets.setdefault(pow2_bucket(int(np.max(b))), []).append(g)
+        t_seg = t
+        with span("segment", t=t_seg, rounds=nxt - t_seg):
+            buckets = {}
+            for g, (b, _) in enumerate(decisions):
+                buckets.setdefault(pow2_bucket(int(np.max(b))), []).append(g)
 
-        seg_losses = [None] * n_cells
-        if len(buckets) == 1:
-            # uniform bucket: the whole grid is one donated carry
-            b_pad, members = next(iter(buckets.items()))
-            idx, rmask, masks, parts = plans(members, t, nxt, b_pad)
-            grid, losses = grid_fn(
-                grid, t0, idx, rmask, masks, arrays_for(members), parts
-            )
-            losses = np.asarray(losses)
-            for g in members:
-                seg_losses[g] = losses[g]
-        else:
-            cells = [_cell_state(grid, g) for g in range(n_cells)]
-            new_cells = [None] * n_cells
-            for b_pad, members in sorted(buckets.items()):
-                idx, rmask, masks, parts = plans(members, t, nxt, b_pad)
-                sub = _stack_cells([cells[g] for g in members])
-                sub, losses = grid_fn(
-                    sub, t0, idx, rmask, masks, arrays_for(members), parts
-                )
-                losses = np.asarray(losses)
-                for j, g in enumerate(members):
-                    new_cells[g] = _cell_state(sub, j)
-                    seg_losses[g] = losses[j]
-            grid = _stack_cells(new_cells)
+            seg_losses = [None] * n_cells
+            if len(buckets) == 1:
+                # uniform bucket: the whole grid is one donated carry
+                b_pad, members = next(iter(buckets.items()))
+                grid, losses = dispatch(grid, members, t, nxt, b_pad)
+                for g in members:
+                    seg_losses[g] = losses[g]
+            else:
+                cells = [_cell_state(grid, g) for g in range(n_cells)]
+                new_cells = [None] * n_cells
+                for b_pad, members in sorted(buckets.items()):
+                    sub = _stack_cells([cells[g] for g in members])
+                    sub, losses = dispatch(sub, members, t, nxt, b_pad)
+                    for j, g in enumerate(members):
+                        new_cells[g] = _cell_state(sub, j)
+                        seg_losses[g] = losses[j]
+                grid = _stack_cells(new_cells)
 
-        for g, sess in enumerate(sessions):
-            b, cuts = decisions[g]
-            clocks[g] = sims[g]._advance_clock(
-                clocks[g], t, nxt, b, cuts, sess.scenario
-            )
-        t = nxt
+            with span("clock", t=t_seg):
+                for g, sess in enumerate(sessions):
+                    b, cuts = decisions[g]
+                    clocks[g] = sims[g]._advance_clock(
+                        clocks[g], t, nxt, b, cuts, sess.scenario
+                    )
+            t = nxt
 
-        at_reconf = t % reconf == 0 and t < rounds
-        at_eval = t % eval_every == 0 or t == rounds
-        if at_reconf or at_eval:
-            # controllers (online G²/σ² estimation) and eval both read
-            # the live per-cell state through the cell's own simulator
-            for g in range(n_cells):
-                sims[g]._stacked = _cell_state(grid, g)
-        if at_reconf:
-            for g, sess in enumerate(sessions):
-                b, cuts = sess.policy(sims[g], sims[g].rng)
-                sims[g]._record_policy(res[g], b, cuts)
-                decisions[g] = (np.asarray(b), np.asarray(cuts))
-        if at_eval:
-            for g in range(n_cells):
-                sims[g]._record_metrics(
-                    res[g], t, clocks[g], seg_losses[g][-1], verbose
-                )
+            at_reconf = t % reconf == 0 and t < rounds
+            at_eval = t % eval_every == 0 or t == rounds
+            if at_reconf or at_eval:
+                # controllers (online G²/σ² estimation) and eval both
+                # read the live per-cell state through the cell's own
+                # simulator
+                for g in range(n_cells):
+                    sims[g]._stacked = _cell_state(grid, g)
+            if at_reconf:
+                with span("control", t=t_seg):
+                    for g, sess in enumerate(sessions):
+                        b, cuts = sess.policy(sims[g], sims[g].rng)
+                        sims[g]._record_policy(res[g], b, cuts)
+                        decisions[g] = (np.asarray(b), np.asarray(cuts))
+            if at_eval:
+                for g in range(n_cells):
+                    sims[g]._record_metrics(
+                        res[g], t, clocks[g], seg_losses[g][-1], verbose,
+                        seg_t=t_seg
+                    )
 
     for g in range(n_cells):
         sims[g]._stacked = _cell_state(grid, g)

@@ -43,6 +43,7 @@ from repro.core import split as SP
 from repro.data.pipeline import DeviceClientStore
 from repro.models.factory import Model
 from repro.training.optim import make_optimizer
+from repro.utils.trace import span
 
 
 def pow2_bucket(n: int) -> int:
@@ -362,13 +363,15 @@ class SFLEdgeSimulator:
         executable covers every (cut, round, fault) combination at a
         given batch shape.
         """
-        losses, grads, scale = self._client_grads(stacked, batch)
-        new_stacked = SP.hasfl_round_update(
-            stacked, grads, masks, do_agg,
-            self.sfl.lr, grad_scale=scale, impl=self._update_ops_impl,
-            participation=part,
-            axis_name=self._axis_name, edge_size=self._edge_size
-        )
+        with jax.named_scope("client_grads"):
+            losses, grads, scale = self._client_grads(stacked, batch)
+        with jax.named_scope("update"):
+            new_stacked = SP.hasfl_round_update(
+                stacked, grads, masks, do_agg,
+                self.sfl.lr, grad_scale=scale, impl=self._update_ops_impl,
+                participation=part,
+                axis_name=self._axis_name, edge_size=self._edge_size
+            )
         return new_stacked, losses
 
     def _scan_segment(self, stacked, t0, idx_seg, row_mask, masks, arrays,
@@ -390,7 +393,8 @@ class SFLEdgeSimulator:
             stacked, t = carry
             idx_r, part_r = xs
             t1 = t + 1
-            batch = DeviceClientStore.device_batch(arrays, idx_r, row_mask)
+            with jax.named_scope("gather"):
+                batch = DeviceClientStore.device_batch(arrays, idx_r, row_mask)
             new_stacked, losses = self._vectorized_round(
                 stacked, batch, masks, (t1 % interval) == 0, part_r)
             return (new_stacked, t1), losses
@@ -659,30 +663,37 @@ class SFLEdgeSimulator:
 
     def _record_metrics(
         self, res: SimResult, t: int, clock: float,
-        losses, verbose: bool, live=None
+        losses, verbose: bool, live=None, seg_t: Optional[int] = None
     ) -> None:
         """Eval + metric append; the only host fetch of ``losses``.
 
         ``live`` ([N] bool, traffic mode) restricts both the aggregate
         model and the train-loss mean to occupied slots — empty slots
         train a weight-0 dummy batch whose loss is meaningless.
+        ``seg_t`` is the first round of the segment that ends here, the
+        ``t`` arg of its spans (the eval round ``t`` when None).
         """
-        agg = self._aggregate_model(live)
-        tl, ta = self._eval_fn(agg, self.test_batch)
+        seg_t = t if seg_t is None else seg_t
+        with span("aggregate", t=seg_t):
+            agg = self._aggregate_model(live)
+        with span("eval", t=seg_t):
+            tl, ta = self._eval_fn(agg, self.test_batch)
         losses = np.asarray(losses)
         if live is not None and live.any():
             losses = losses[np.asarray(live, bool)]
         mean_loss = float(np.mean(losses))
+        with span("eval_fetch", t=seg_t):
+            tl, ta = float(tl), float(ta)
         res.rounds.append(t)
         res.clock.append(clock)
         res.train_loss.append(mean_loss)
-        res.test_loss.append(float(tl))
-        res.test_acc.append(float(ta))
+        res.test_loss.append(tl)
+        res.test_acc.append(ta)
         if verbose:
             print(
                 f"round {t:5d} clock {clock:9.1f}s "
                 f"loss {mean_loss:.4f} "
-                f"acc {float(ta):.4f}", flush=True
+                f"acc {ta:.4f}", flush=True
             )
 
     def _segment_participation(self, t: int, nxt: int, b, cuts, scenario):
@@ -746,37 +757,53 @@ class SFLEdgeSimulator:
             )
             if ckpt:
                 nxt = min(nxt, (t // ckpt + 1) * ckpt)
-            ucuts = self._unit_cuts(np.asarray(cuts))
-            l_c_units = int(np.max(ucuts))
-            masks = jnp.asarray(SP.client_unit_mask(self.cfg, n_units_total, l_c_units))
-            b_pad = pow2_bucket(int(np.max(b)))
-            idx = self.store.segment_indices(nxt - t, b, b_pad)
-            row_mask = self.store.row_mask(b, b_pad)
-            parts = self._segment_participation(t, nxt, b, cuts, scenario)
-            self._stacked, seg_losses = self._scan_fn(
-                self._stacked, jnp.asarray(t, jnp.int32), idx, row_mask,
-                masks, self.store.arrays, parts)
+            t0 = t
+            with span("segment", t=t0, rounds=nxt - t0):
+                with span("plan", t=t0):
+                    ucuts = self._unit_cuts(np.asarray(cuts))
+                    l_c_units = int(np.max(ucuts))
+                    masks = jnp.asarray(SP.client_unit_mask(
+                        self.cfg, n_units_total, l_c_units))
+                    b_pad = pow2_bucket(int(np.max(b)))
+                    idx = self.store.segment_indices(nxt - t, b, b_pad)
+                    row_mask = self.store.row_mask(b, b_pad)
+                    parts = self._segment_participation(
+                        t, nxt, b, cuts, scenario)
+                with span("dispatch", t=t0,
+                          rows=int(np.sum(b)) * (nxt - t),
+                          padded_rows=self.n * b_pad * (nxt - t)):
+                    self._stacked, seg_losses = self._scan_fn(
+                        self._stacked, jnp.asarray(t, jnp.int32), idx,
+                        row_mask, masks, self.store.arrays, parts)
 
-            # clock: accumulate round-by-round on host (bitwise-identical
-            # float summation to the per-round engines)
-            clock = self._advance_clock(clock, t, nxt, b, cuts, scenario)
-            t = nxt
+                # clock: accumulate round-by-round on host (bitwise-
+                # identical float summation to the per-round engines)
+                with span("clock", t=t0):
+                    clock = self._advance_clock(
+                        clock, t, nxt, b, cuts, scenario)
+                t = nxt
 
-            if self._bank is not None and t < rounds \
-                    and t % self.sfl.agg_interval == 0:
-                # cohort rotation at the agg-aligned boundary: the
-                # departing cohort's state is already folded into the
-                # Eq. 7 broadcast, so the bank swaps pools/profiles and
-                # re-broadcasts the aggregate (DESIGN.md §15)
-                self._bank.rotate(self, t)
-            b, cuts = self._maybe_reconfigure(
-                res, policy_fn, t, reconf,
-                rounds, b, cuts
-            )
-            if t % eval_every == 0 or t == rounds:
-                # one [R, N] loss fetch per segment; the eval round is the
-                # segment's last, so its losses are the final ys row
-                self._record_metrics(res, t, clock, np.asarray(seg_losses)[-1], verbose)
+                if self._bank is not None and t < rounds \
+                        and t % self.sfl.agg_interval == 0:
+                    # cohort rotation at the agg-aligned boundary: the
+                    # departing cohort's state is already folded into
+                    # the Eq. 7 broadcast, so the bank swaps
+                    # pools/profiles and re-broadcasts the aggregate
+                    # (DESIGN.md §15)
+                    self._bank.rotate(self, t)
+                with span("control", t=t0):
+                    b, cuts = self._maybe_reconfigure(
+                        res, policy_fn, t, reconf,
+                        rounds, b, cuts
+                    )
+                if t % eval_every == 0 or t == rounds:
+                    # one [R, N] loss fetch per segment; the eval round
+                    # is the segment's last, so its losses are the final
+                    # ys row
+                    with span("fetch", t=t0):
+                        last = np.asarray(seg_losses)[-1]
+                    self._record_metrics(res, t, clock, last, verbose,
+                                         seg_t=t0)
             if ckpt and snapshot_cb is not None and t % ckpt == 0:
                 # after reconfigure/eval: the snapshot captures the
                 # decisions and metrics exactly as the resumed loop needs

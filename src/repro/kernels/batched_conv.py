@@ -32,6 +32,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+# The `jax.named_scope` of the patch layout around the matmul: pads,
+# patch extraction, the stride dilation of dy, and the reshapes and
+# transpose that feed the matmul.  The matmul itself stays outside it.
+IM2COL = "im2col"
+
+
 def same_geometry(h: int, w: int, kh: int, kw: int, stride: int):
     """(ho, wo, pad_h_lo, pad_h_hi, pad_w_lo, pad_w_hi) for SAME padding."""
     ho, wo = -(-h // stride), -(-w // stride)
@@ -133,10 +139,12 @@ def _conv_fwd(x, w, b, stride: int, mm):
     n, bsz, h, wd, _ = x.shape
     kh, kw, cout = w.shape[1], w.shape[2], w.shape[4]
     ho, wo, plo_h, phi_h, plo_w, phi_w = same_geometry(h, wd, kh, kw, stride)
-    xp = jnp.pad(x, ((0, 0), (0, 0), (plo_h, phi_h), (plo_w, phi_w), (0, 0)))
-    pat = extract_patches(xp, kh, kw, ho, wo, stride)
-    out = mm(pat.reshape(n, bsz * ho * wo, -1),
-             w.reshape(n, -1, cout)).reshape(n, bsz, ho, wo, cout)
+    with jax.named_scope(IM2COL):
+        xp = jnp.pad(x, ((0, 0), (0, 0), (plo_h, phi_h), (plo_w, phi_w),
+                         (0, 0)))
+        pat = extract_patches(xp, kh, kw, ho, wo, stride)
+        pat = pat.reshape(n, bsz * ho * wo, -1)
+    out = mm(pat, w.reshape(n, -1, cout)).reshape(n, bsz, ho, wo, cout)
     return out + b[:, None, None, None, :]
 
 
@@ -155,29 +163,30 @@ def _conv_bwd(x, w, dy, stride: int, mm):
 
     db = dy.sum(axis=(1, 2, 3))
 
-    xp = jnp.pad(x, ((0, 0), (0, 0), (plo_h, phi_h), (plo_w, phi_w), (0, 0)))
-    pat = extract_patches(xp, kh, kw, ho, wo, stride)
-    dw = mm(
-        pat.reshape(n, bsz * ho * wo, -1).transpose(0, 2, 1),
-        dy.reshape(n, bsz * ho * wo, cout),
-    ).reshape(w.shape)
+    with jax.named_scope(IM2COL):
+        xp = jnp.pad(x, ((0, 0), (0, 0), (plo_h, phi_h), (plo_w, phi_w),
+                         (0, 0)))
+        pat = extract_patches(xp, kh, kw, ho, wo, stride)
+        pat_t = pat.reshape(n, bsz * ho * wo, -1).transpose(0, 2, 1)
+    dw = mm(pat_t, dy.reshape(n, bsz * ho * wo, cout)).reshape(w.shape)
 
     # dx: dy dilated to the input stride grid, padded so index algebra
     # dx[i] = sum_j dy_dil[i + lo - (kh-1) + j] * w[kh-1-j] becomes a
     # VALID stride-1 correlation producing exactly [H, W].
     hd, wdl = (ho - 1) * stride + 1, (wo - 1) * stride + 1
-    if stride > 1:
-        dyd = jnp.zeros((n, bsz, hd, wdl, cout), dy.dtype)
-        dyd = dyd.at[:, :, ::stride, ::stride, :].set(dy)
-    else:
-        dyd = dy
-    dyp = jnp.pad(dyd, ((0, 0), (0, 0),
-                        (kh - 1 - plo_h, h + plo_h - hd),
-                        (kw - 1 - plo_w, wd + plo_w - wdl), (0, 0)))
-    dpat = extract_patches(dyp, kh, kw, h, wd, 1)
+    with jax.named_scope(IM2COL):
+        if stride > 1:
+            dyd = jnp.zeros((n, bsz, hd, wdl, cout), dy.dtype)
+            dyd = dyd.at[:, :, ::stride, ::stride, :].set(dy)
+        else:
+            dyd = dy
+        dyp = jnp.pad(dyd, ((0, 0), (0, 0),
+                            (kh - 1 - plo_h, h + plo_h - hd),
+                            (kw - 1 - plo_w, wd + plo_w - wdl), (0, 0)))
+        dpat = extract_patches(dyp, kh, kw, h, wd, 1)
+        dpat = dpat.reshape(n, bsz * h * wd, -1)
     w_rot = jnp.flip(w, axis=(1, 2)).transpose(0, 1, 2, 4, 3)
-    dx = mm(dpat.reshape(n, bsz * h * wd, -1),
-            w_rot.reshape(n, -1, cin)).reshape(x.shape)
+    dx = mm(dpat, w_rot.reshape(n, -1, cin)).reshape(x.shape)
     return dx, dw, db
 
 
