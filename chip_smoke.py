@@ -13,9 +13,13 @@ process, phases in order:
    clock increasing;
 3. reference engine: a fixed-policy spec on the scan engine against the
    per-round vectorized engine;
-4. kernel path: the same spec through ``Session.run_grid(runner="auto")``
-   — on TPU the native Pallas batched conv and fused clip+SGD — whose
-   executable must hold ``tpu_custom_call``, against phase 3.
+4. kernel path: ``runner="auto"`` must leave a CNN spec's kernel knobs
+   unset on a TPU (XLA's convolution and the inline update); the same
+   spec with both knobs pinned to ``"kernel"`` — the native Pallas
+   batched conv and fused clip+SGD — goes through
+   ``Session.run_grid(runner="auto")`` with its pins kept, its
+   executable must hold ``tpu_custom_call``, and it is checked against
+   phase 3.
 
 ``--four-chips`` runs only the mesh path and its reference instead: the
 phase-3 spec sharded over four devices against the same spec on one.
@@ -222,13 +226,14 @@ def phase_kernel(dev, ref) -> None:
     from repro.api import Session
     from repro.api import runners as R
 
-    spec = fixed_spec()
-    chosen = R.apply_choice(spec)
-    say(f"  runner auto -> conv_impl={chosen.conv_impl} "
-        f"update_impl={chosen.update_impl}")
-    check(chosen.conv_impl == "kernel" and chosen.update_impl == "kernel",
-          "runner='auto' did not pick the kernel path on this backend")
-    text, ma = kernel_executable_text(chosen)
+    auto = R.apply_choice(fixed_spec())
+    say(f"  runner auto -> conv_impl={auto.conv_impl} "
+        f"update_impl={auto.update_impl}")
+    check(auto.conv_impl is None and auto.update_impl is None,
+          "runner='auto' filled a kernel knob of a CNN spec on this backend")
+    spec = fixed_spec(conv_impl="kernel", update_impl="kernel")
+    check(R.apply_choice(spec) == spec, "runner='auto' changed a pinned spec")
+    text, ma = kernel_executable_text(spec)
     check("tpu_custom_call" in text,
           "kernel-path scan executable holds no tpu_custom_call")
     total = (ma.argument_size_in_bytes + ma.output_size_in_bytes

@@ -41,7 +41,8 @@ class ExecutionChoice:
 
 _DEFAULT = ExecutionChoice()
 
-# Measured regimes (DESIGN.md §11; benchmarks/ committed wall_s rows):
+# Measured regimes (DESIGN.md §11; CPU rows from benchmarks/ committed
+# wall_s rows, TPU rows from chip runs on a v5e recorded in PERF.md):
 # - CNN cells on a SINGLE CPU core: sequential + the im2col custom-vjp
 #   conv ("kernel" dispatches to it off-TPU).  The kernel collapses the
 #   vgg9 smoke sweep 1291.0 s -> 91.3 s sequential; the grid runner,
@@ -54,12 +55,24 @@ _DEFAULT = ExecutionChoice()
 #   the row from the visible core count at pick time.
 # - token cells: grid + oracle (the dispatch-economy regime — 2.02x on
 #   the smollm-tiny sweep; no conv to replace).
-# - TPU rows keep the grid (batching feeds the MXU instead of fighting
-#   a cache) and also fuse the clip+SGD update, a no-op gain on CPU
-#   where "kernel" update dispatch falls back to the same jnp algebra.
+# - CNN cells on a TPU have no row, so they take the default: grid +
+#   oracle.  The vmapped `lax.conv` lowers to XLA's grouped convolution,
+#   which runs VGG-16 at N=20, b=16 at 90.5% of its byte roofline; the
+#   Pallas im2col matmul and its patch copies read 10.4% and make the
+#   round 7.2x slower (185.5 vs 25.7 ms on one v5e; PERF.md §5).  The
+#   fused clip+SGD kernel itself takes 6.9 ms per round, but XLA
+#   relayouts every [N, ...] leaf into and out of its [N, D] operands
+#   (client axis padded 20 -> 24) for about 25 ms more: 48.1 ms per
+#   round against 25.4 with the inline update (PERF.md §6).
+#   Multi-cell CNN groups keep the default grid runner: vmapped over
+#   [G] cells, XLA's convs run 2 VGG-16 cells at b=8 in 82.3 ms per
+#   round where both kernels took 263.5, and at b=16 two or three cells
+#   fit (102.4 / 163.4 ms) where the kernels' scratch did not fit one
+#   v5e.  Whether running such cells one by one beats the grid is
+#   unmeasured (ROADMAP Speed 5).
+# - the token/TPU row's grid runner and update kernel are unmeasured on
+#   the chip (ROADMAP Speed 5).
 _REGISTRY = {
-    ("cnn", "tpu"): ExecutionChoice("grid", conv_impl="kernel",
-                                    update_impl="kernel"),
     ("token", "tpu"): ExecutionChoice("grid", update_impl="kernel"),
 }
 

@@ -109,6 +109,42 @@ def test_registry_pick_and_apply_choice():
         R.ExecutionChoice("warp")
 
 
+def test_registry_tpu_rows(monkeypatch):
+    """On a TPU a CNN spec gets no kernel knob filled: XLA's grouped
+    conv and the inline update.  The token row keeps its update kernel,
+    and a knob the spec pins survives a choice that fills the other."""
+    monkeypatch.setattr(R.jax, "default_backend", lambda: "tpu")
+    spec = tiny_spec()
+    assert R.pick(spec) == R.ExecutionChoice(
+        "grid", conv_impl=None, update_impl=None)
+    filled = R.apply_choice(spec)
+    assert filled.conv_impl is None and filled.update_impl is None
+    token = tiny_spec(arch="smollm-tiny", partition="iid")
+    token_row = R.ExecutionChoice("grid", update_impl="kernel")
+    assert R.pick(token) == token_row
+    assert R.apply_choice(token).update_impl == "kernel"
+    assert R.apply_choice(token.replace(update_impl="ref")).update_impl == "ref"
+    pinned = R.apply_choice(tiny_spec(conv_impl="kernel"), token_row)
+    assert (pinned.conv_impl, pinned.update_impl) == ("kernel", "kernel")
+
+
+def test_oracle_conv_with_pallas_update_matches_ref():
+    """Vmap-of-grad gradients (the path the token/TPU row and a spec
+    pinning only ``update_impl`` take) into the Pallas clip+SGD
+    (interpret mode here) track the jnp update — clocks and decisions
+    exact, losses to fp32 tolerance."""
+    r_ref = Session(tiny_spec(update_impl="ref")).run()
+    r_pallas = Session(tiny_spec(update_impl="interpret")).run()
+    assert r_pallas.clock == r_ref.clock
+    for hist in ("b_history", "cut_history"):
+        np.testing.assert_array_equal(getattr(r_pallas, hist),
+                                      getattr(r_ref, hist))
+    np.testing.assert_allclose(r_pallas.train_loss, r_ref.train_loss,
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(r_pallas.test_loss, r_ref.test_loss,
+                               rtol=1e-5, atol=1e-6)
+
+
 def test_runner_auto_rejects_built_sessions():
     sess = Session(tiny_spec())
     with pytest.raises(ValueError, match="auto"):
