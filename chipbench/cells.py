@@ -8,13 +8,18 @@ has files of its own under ``chipbench/``:
   entry point (read by `harness.build_spec` and `harness.make_fleet`);
 - ``limits/<workload>.json``: the limit of every number `correct`
   compares, with the readings it was set from;
-- ``metrics/<metric>.py``: one reader per metric, ``read(ctx)``.
+- ``metrics/<metric>.py``: one reader per metric, ``read(ctx)``;
+- ``reference/<family>.py`` and ``counts/<family>.py``: the plain
+  reference and the operation counts of the model family a
+  configuration names by its ``reference`` key (`family`).
 """
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import os
+from types import SimpleNamespace
 from typing import Dict, List
 
 BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
@@ -59,6 +64,47 @@ def load_cell(workload: str, root: str = ROOT) -> Dict:
         "end_to_end": metrics_for(bench, workload, "end_to_end"),
         "per_layer": metrics_for(bench, workload, "per_layer"),
     }
+
+
+# what each module of a family exports
+FAMILY_EXPORTS = {
+    "reference": ("data", "init_params", "Trainer", "leaf_names"),
+    "counts": ("train_flops", "forward_flops", "param_count", "profile"),
+}
+
+
+def family(cfg: Dict) -> SimpleNamespace:
+    """The modules of the configuration's model family, ``reference`` and
+    ``counts``: ``chipbench/<part>/<cfg["reference"]>.py``.
+
+    - ``reference``: ``data(cfg, traffic, seed)`` (the train and test
+      sets, made from the seed), ``init_params(cfg, seed)``, ``Trainer``
+      (``round``, ``evaluate``, ``delta_norms``) and ``leaf_names(cfg)``;
+    - ``counts``: ``train_flops(cfg, traffic)`` and
+      ``forward_flops(cfg, traffic)`` (operations per row),
+      ``param_count(cfg)``, and ``profile(cfg, traffic)``, the per-unit
+      costs ``rho``/``bwd``/``psi``/``chi``/``delta``/``params`` that
+      `reference.control` walks.
+    """
+    name = cfg["reference"]
+    mods = {}
+    for part, exports in FAMILY_EXPORTS.items():
+        full = f"chipbench.{part}.{name}"
+        try:
+            mod = importlib.import_module(full)
+        except ModuleNotFoundError as e:
+            if e.name != full:
+                raise
+            raise ModuleNotFoundError(
+                f"configuration {cfg.get('name')!r} names the family "
+                f"{name!r}, but chipbench/{part}/{name}.py is missing",
+                name=full) from None
+        missing = [f for f in exports if not hasattr(mod, f)]
+        if missing:
+            raise AttributeError(f"chipbench/{part}/{name}.py does not "
+                                 f"define {', '.join(missing)}")
+        mods[part] = mod
+    return SimpleNamespace(**mods)
 
 
 def reader(metric: str):

@@ -5,8 +5,9 @@ set-up, taken through the window's own executable and feed: each
 client's loss in every round, the eval loss at each segment's end, and
 the norm of each parameter leaf's change from the initial weights at
 the rounds ``check.delta_at`` (the state is only reachable at segment
-boundaries).  The reference (`reference/cnn.py`) replays those rounds
-from the same seed: its own weights, its own copy of the data, the
+boundaries).  The reference of the configuration's family
+(`cells.family`: ``reference/<family>.py``) replays those rounds from
+the same seed: its own weights, its own copy of the data, the
 program's gather plan (which samples each client draws) and its
 decisions (b, cut).  Numbers compared:
 
@@ -45,9 +46,8 @@ from typing import Dict
 
 import numpy as np
 
-from chipbench.reference import cnn as REF
+from chipbench import cells
 from chipbench.reference import control as CTL
-from chipbench.reference.data import cifar_like
 
 
 def reference_readings(cell: Dict, seed: int, program: Dict, *,
@@ -59,16 +59,15 @@ def reference_readings(cell: Dict, seed: int, program: Dict, *,
     cfg, traffic = cell["config"], cell["traffic"]
     chk = traffic["check"]
     n = traffic["fleet"]["n"]
-    (xtr, ytr), (xte, yte) = cifar_like(
-        cfg["n_classes"], traffic["n_train"], traffic["n_test"],
-        cfg["image_size"], seed)
-    init = REF.init_params(cfg, seed)
+    ref = cells.family(cfg).reference
+    (xtr, ytr), (xte, yte) = ref.data(cfg, traffic, seed)
+    init = ref.init_params(cfg, seed)
     if program.get("init") is not None:
         worst = max(float(np.max(np.abs(np.asarray(init[k]) - v)))
                     for k, v in program["init"].items())
         print(f"initial weights: largest gap to the program's {worst!r}",
               flush=True)
-    tr = REF.Trainer(cfg, init, n, lr=traffic["lr"],
+    tr = ref.Trainer(cfg, init, n, lr=traffic["lr"],
                      clip=traffic["clip_norm"],
                      agg_interval=traffic["agg_interval"],
                      dtype=getattr(jnp, dtype), precision=precision)
@@ -140,7 +139,8 @@ def host_numbers(cell: Dict, prog: Dict, *, dtype=np.float64) -> Dict:
     reference clock put in the program's place (the control)."""
     traffic = cell["traffic"]
     ctl = traffic["controller"]
-    prof = CTL.profile(cell["config"])
+    prof = cells.family(cell["config"]).counts.profile(cell["config"],
+                                                      traffic)
     conv = dict(ctl, lr=traffic["lr"], agg_interval=traffic["agg_interval"])
     fleet, decisions = prog["fleet"], prog["decisions"]
     clocks = prog["clocks"]

@@ -12,12 +12,18 @@ share a lower bound.
 Minimal bytes are float32 reads of each operand and writes of each
 result, once per call, with weights read once per client (every client
 holds its own copy).
+
+The family's counts (`cells.family`) take the traffic beside the
+configuration; a CNN's counts do not depend on it.
 """
 from __future__ import annotations
 
-from typing import Dict, List
+from typing import Dict, List, Optional
+
+import numpy as np
 
 F32 = 4
+BITS = 32
 
 
 def layers(cfg: Dict) -> List[Dict]:
@@ -78,12 +84,14 @@ def param_count(cfg: Dict) -> int:
                for l in layers(cfg))
 
 
-def forward_flops(cfg: Dict, kinds=("conv", "dense")) -> int:
+def forward_flops(cfg: Dict, traffic: Optional[Dict] = None,
+                  kinds=("conv", "dense")) -> int:
     """Forward operations for one sample."""
     return sum(fwd_flops(l) for l in layers(cfg) if l["kind"] in kinds)
 
 
-def train_flops(cfg: Dict, kinds=("conv", "dense")) -> int:
+def train_flops(cfg: Dict, traffic: Optional[Dict] = None,
+                kinds=("conv", "dense")) -> int:
     """Forward plus backward operations for one sample.
 
     The backward pass makes a weight gradient (as many operations as the
@@ -96,6 +104,45 @@ def train_flops(cfg: Dict, kinds=("conv", "dense")) -> int:
             f = fwd_flops(l)
             total += f * (2 if l["first"] else 3)
     return total
+
+
+def profile(cfg: Dict, traffic: Optional[Dict] = None) -> Dict[str, np.ndarray]:
+    """Per-unit costs of the configuration, cumulative where noted, as
+    `reference.control` walks them.  For a cut after unit j (1-based, as
+    the decisions count): ``rho`` the cumulative forward operations per
+    sample (every tap of every 3x3 kernel at every output position, the
+    usual count; a residual unit's 3x3 stride-2 projection counted as
+    built), ``bwd`` twice that, ``psi``/``chi`` the activation (and its
+    gradient) leaving unit j, in bits, ``delta`` the bits of units
+    1..j's parameters, and ``params`` each unit's parameter count.
+    Sizes are float32."""
+    flops, params, act = [], [], []
+    hw, cin = cfg["image_size"], cfg["in_channels"]
+    pools = set(cfg.get("pool_after", []))
+    residual = cfg.get("residual", False)
+    for i, c in enumerate(cfg["conv_channels"]):
+        strided = residual and i > 0 and c != cin
+        if strided:
+            hw = -(-hw // 2)
+        convs = 2 if strided else 1       # the projection is a 3x3 conv too
+        flops.append(convs * 2 * 9 * cin * c * hw * hw)
+        params.append(convs * (9 * cin * c + c))
+        cin = c
+        if i + 1 in pools:
+            hw = max(1, hw // 2)
+        act.append(c * hw * hw)
+    prev = cin if residual else cin * hw * hw
+    for f in list(cfg["fc_dims"]) + [cfg["n_classes"]]:
+        flops.append(2 * prev * f)
+        params.append(prev * f + f)
+        act.append(f)
+        prev = f
+    flops = np.asarray(flops, np.float64)
+    psi = np.asarray(act, np.float64) * BITS
+    return {"rho": np.cumsum(flops), "bwd": np.cumsum(2.0 * flops),
+            "psi": psi, "chi": psi.copy(),
+            "delta": np.cumsum(np.asarray(params, np.float64)) * BITS,
+            "params": np.asarray(params, np.float64)}
 
 
 def _io_elems(layer: Dict, batch: int):

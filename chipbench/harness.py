@@ -38,6 +38,7 @@ import numpy as np
 
 from chipbench import cells
 from chipbench import compare as CMP
+from chipbench import program_trace as PT
 from chipbench import trace as TR
 
 TRACE_DIR = os.path.join(cells.BENCH_DIR, ".out", "trace")
@@ -84,6 +85,8 @@ def build_spec(cfg: Dict, traffic: Dict, seed: int):
 
     mesh = traffic.get("mesh")
     ctl = traffic["controller"]
+    # a token family's sequence length; a CNN's mix has none
+    extra = {"seq_len": traffic["seq_len"]} if "seq_len" in traffic else {}
     return ExperimentSpec(
         arch=cfg["arch"], n_clients=traffic["fleet"]["n"],
         partition=traffic["partition"], n_train=traffic["n_train"],
@@ -96,6 +99,7 @@ def build_spec(cfg: Dict, traffic: Dict, seed: int):
         sfl=SFLConfig(agg_interval=traffic["agg_interval"],
                       lr=traffic["lr"], clip_norm=traffic["clip_norm"],
                       **{k: ctl[k] for k in SFL_KEYS}),
+        **extra,
     )
 
 
@@ -349,7 +353,11 @@ class Probe:
 # ---------------------------------------------------------------------------
 
 class Context:
-    """What a metric reader may read (see ``metrics/<name>.py``)."""
+    """What a metric reader may read (see ``metrics/<name>.py``): the
+    cell, the window, host times, and in a traced run ``trace`` and
+    ``summary`` (`chipbench.trace`) and ``program``, the same trace by
+    the program's spans and scopes (`chipbench.program_trace`); those
+    three are None in an untraced run."""
 
     def __init__(self, **kw):
         self.__dict__.update(kw)
@@ -466,12 +474,15 @@ def run_cell(cell: Dict, seed: int, seconds: float, tracing: bool, *,
         f"first; b {int(b.min())}-{int(b.max())} (sum {int(b.sum())}), "
         f"b_pad {1 << max(0, int(b.max()) - 1).bit_length()}, "
         f"cuts {sorted(set(cuts.tolist()))}")
-    tr = summ = None
+    tr = summ = prog_tr = None
     if tracing:
         if keep_trace:
             shutil.copytree(TRACE_DIR, keep_trace, dirs_exist_ok=True)
-        tr = TR.load(TRACE_DIR)
+        events = TR.events(TRACE_DIR)
+        tr = TR.parse(events)
         summ = TR.summary(tr)
+        prog_tr = PT.parse(events, tr.window())
+        del events
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
     # free the program's state before the reference runs
     program = readings(probe)
@@ -483,6 +494,7 @@ def run_cell(cell: Dict, seed: int, seconds: float, tracing: bool, *,
         window=win, setup_s=setup_s, session_s=probe.session_s,
         peak_bytes=peak, chips=w["chips"], trace=tr, summary=summ,
         b=b, cuts=cuts, host_s=probe.host_s,
+        program=prog_tr,
         peaks=cells.peaks(kind) if devices[0].platform == "tpu" else None)
     kind_metrics = cell["per_layer"] if tracing else cell["end_to_end"]
     metrics = {}

@@ -1,30 +1,28 @@
-"""The fused clip+SGD kernel's share of its roofline.
+"""The clip+SGD update's share of its roofline.
 
 Every round reads each client's parameters and gradients and writes its
-parameters once, in float32: 12 bytes per parameter per client.  The
-least time is those bytes over HBM bandwidth; the measured time is the
-device time of the kernel's Pallas calls in the traced window.
+parameters once, in float32: 12 bytes per parameter per client, the
+parameters counted by the family's counts (`cells.family`).  The least
+time is those bytes over HBM bandwidth; the measured time is the device
+time, summed over the chips, of the window's ops under the program's
+``update`` scope (`program_trace.UPDATE`): the inline update, or the
+Pallas kernel of `kernels/clip_sgd.py` with the relayouts XLA puts
+around it where a spec pins ``update_impl``.  The name is the kernel's,
+whose place the inline update took.
 """
-from chipbench import trace as TR
-from chipbench.counts import cnn
-
-def is_update(op):
-    """The Pallas calls of `kernels/clip_sgd.py` (named ``clip_sgd.<n>``
-    after the jitted function that makes them)."""
-    return op.category == "custom-call" and op.name.startswith("clip_sgd")
+from chipbench import cells
+from chipbench import program_trace as PT
 
 
 def read(ctx):
-    s = ctx.summary
-    if s is None or ctx.peaks is None:
+    pt = ctx.program
+    if pt is None or ctx.peaks is None:
         return None
-    lo, hi = ctx.trace.window()
-    busy = sum(TR.op_time(ops, is_update, lo, hi)
-               for ops in ctx.trace.ops.values())
+    busy = PT.scope_seconds(pt, PT.UPDATE)
     if busy <= 0:
         return None
     n = ctx.traffic["fleet"]["n"]
     rounds = ctx.traffic["trace_rounds"]
-    least = rounds * 12 * n * cnn.param_count(ctx.cfg) \
-        / ctx.peaks["hbm_bytes_per_s"]
+    least = rounds * 12 * n * cells.family(ctx.cfg).counts.param_count(
+        ctx.cfg) / ctx.peaks["hbm_bytes_per_s"]
     return 100.0 * least / busy

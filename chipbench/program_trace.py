@@ -28,10 +28,6 @@ op has an owner.
 """
 from __future__ import annotations
 
-import glob
-import gzip
-import json
-import os
 from collections import Counter, defaultdict
 from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Optional, Tuple
@@ -45,7 +41,8 @@ DISPATCH = PREFIX + "dispatch"
 # host work
 WAITS = (PREFIX + "fetch", PREFIX + "eval_fetch")
 # the scan segment's device steps, and the kernel conv's patch layout
-SEGMENT_SCOPES = ("gather", "client_grads", "update")
+UPDATE = "update"
+SEGMENT_SCOPES = ("gather", "client_grads", UPDATE)
 IM2COL = "im2col"
 SCAN_MODULE = "jit__scan_segment("
 Interval = Tuple[float, float]
@@ -141,13 +138,7 @@ def parse(events: List[Dict], window: Interval) -> ProgramTrace:
 def load(trace_dir: str) -> ProgramTrace:
     """Read the newest ``*.trace.json.gz`` under ``trace_dir``; the
     window is `chipbench.trace`'s."""
-    files = sorted(glob.glob(os.path.join(
-        trace_dir, "plugins", "profile", "*", "*.trace.json.gz")),
-        key=os.path.getmtime)
-    if not files:
-        raise FileNotFoundError(f"no .trace.json.gz under {trace_dir}")
-    with gzip.open(files[-1], "rt") as f:
-        events = json.load(f)["traceEvents"]
+    events = TR.events(trace_dir)
     return parse(events, TR.parse(events).window())
 
 
@@ -209,14 +200,20 @@ def idle_shares(pt: ProgramTrace) -> Optional[Tuple[float, float]]:
     return outs * scale, ins * scale
 
 
+def scope_seconds(pt: ProgramTrace, scope: str) -> float:
+    """Device seconds of the window's ops under ``scope``, summed over
+    the chips (control flow left out)."""
+    lo, hi = pt.window
+    return sum(TR.length(TR.clip(((o.start, o.end) for o in work(ops)
+                                  if o.under(scope)), lo, hi))
+               for ops in pt.ops.values())
+
+
 def scope_ms_per_round(pt: ProgramTrace, scope: str,
                        rounds: int) -> Optional[float]:
     """Device ms per round and chip of the window's ops under ``scope``;
     None where no op carries the scope (a program without it)."""
-    lo, hi = pt.window
-    s = sum(TR.length(TR.clip(((o.start, o.end) for o in work(ops)
-                               if o.under(scope)), lo, hi))
-            for ops in pt.ops.values())
+    s = scope_seconds(pt, scope)
     if s <= 0 or not rounds:
         return None
     return 1e3 * s / rounds / len(pt.ops)
@@ -343,7 +340,7 @@ def report(pt: ProgramTrace, rounds: int) -> Dict:
         "loop.idle_share": None if shares is None else shares[0],
         "segment.idle_share": None if shares is None else shares[1],
         "segment.grads_ms": scope_ms_per_round(pt, "client_grads", rounds),
-        "segment.update_ms": scope_ms_per_round(pt, "update", rounds),
+        "segment.update_ms": scope_ms_per_round(pt, UPDATE, rounds),
         "segment.gather_ms": scope_ms_per_round(pt, "gather", rounds),
         "conv.im2col_ms": scope_ms_per_round(pt, IM2COL, rounds),
         "segment.row_use": row_use(pt),

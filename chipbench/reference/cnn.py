@@ -26,6 +26,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench.reference.data import cifar_like
+
+
+def data(cfg: Dict, traffic: Dict, seed: int):
+    """``((train images, labels), (test images, labels))`` of the cell,
+    made from the seed."""
+    return cifar_like(cfg["n_classes"], traffic["n_train"],
+                      traffic["n_test"], cfg["image_size"], seed)
+
 
 def leaf_names(cfg: Dict) -> List[str]:
     """Names of the parameter leaves, ``<unit>.<w|b>`` and

@@ -1,9 +1,9 @@
 """Plain reference of the host control loop's output: the simulated clock
 and the quality of a HASFL decision.
 
-Both follow arXiv:2506.08426 from the configuration's own layer shapes
-and the fleet the traffic file describes; nothing here imports the
-program.
+Both follow arXiv:2506.08426 from the configuration's per-unit costs
+(``profile(cfg, traffic)`` of its family's counts, `cells.family`) and
+the fleet the traffic file describes; nothing here imports the program.
 
 - The clock (Eqs. 28-40): every round adds the split-training latency
   T_S (Eq. 38), and every ``agg_interval``-th round the aggregation
@@ -14,52 +14,17 @@ program.
   Corollary 1 has no solution (eps at or below the variance and drift
   terms).
 
-Per-layer costs, for a cut after unit j (1-based, as the decisions
-count): ``rho`` the cumulative forward operations per sample (every tap
-of every 3x3 kernel at every output position, the usual count; a
-residual unit's 3x3 stride-2 projection counted as built), ``bwd``
-twice that, ``psi``/``chi`` the activation (and its gradient) leaving
-unit j, in bits, and ``delta`` the bits of units 1..j's parameters.
-Sizes are float32.
+A profile holds, for a cut after unit j (1-based, as the decisions
+count): ``rho`` the cumulative forward operations per sample, ``bwd``
+the cumulative backward operations, ``psi``/``chi`` the activation (and
+its gradient) leaving unit j, in bits, ``delta`` the bits of units
+1..j's parameters, and ``params`` each unit's parameter count.
 """
 from __future__ import annotations
 
 from typing import Dict, List, Sequence
 
 import numpy as np
-
-BITS = 32
-
-
-def profile(cfg: Dict) -> Dict[str, np.ndarray]:
-    """Per-unit costs of the configuration, cumulative where noted."""
-    flops, params, act = [], [], []
-    hw, cin = cfg["image_size"], cfg["in_channels"]
-    pools = set(cfg.get("pool_after", []))
-    residual = cfg.get("residual", False)
-    for i, c in enumerate(cfg["conv_channels"]):
-        strided = residual and i > 0 and c != cin
-        if strided:
-            hw = -(-hw // 2)
-        convs = 2 if strided else 1       # the projection is a 3x3 conv too
-        flops.append(convs * 2 * 9 * cin * c * hw * hw)
-        params.append(convs * (9 * cin * c + c))
-        cin = c
-        if i + 1 in pools:
-            hw = max(1, hw // 2)
-        act.append(c * hw * hw)
-    prev = cin if residual else cin * hw * hw
-    for f in list(cfg["fc_dims"]) + [cfg["n_classes"]]:
-        flops.append(2 * prev * f)
-        params.append(prev * f + f)
-        act.append(f)
-        prev = f
-    flops = np.asarray(flops, np.float64)
-    psi = np.asarray(act, np.float64) * BITS
-    return {"rho": np.cumsum(flops), "bwd": np.cumsum(2.0 * flops),
-            "psi": psi, "chi": psi.copy(),
-            "delta": np.cumsum(np.asarray(params, np.float64)) * BITS,
-            "params": np.asarray(params, np.float64)}
 
 
 def _fleet(fleet: Sequence[Dict], key: str) -> np.ndarray:

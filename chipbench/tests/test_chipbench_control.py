@@ -11,6 +11,7 @@ from chipbench_tiny import ROOT
 
 from chipbench import cells, harness
 from chipbench import compare as CMP
+from chipbench.counts import cnn
 from chipbench.reference import control as CTL
 
 CELL = cells.load_cell("vgg16.fixed16.auto", ROOT)
@@ -37,7 +38,7 @@ def _conv(lr):
 
 def test_profile_and_round_times_match_the_program():
     fleet, opt = _program(TRAFFIC["lr"])
-    prof = CTL.profile(CELL["config"])
+    prof = cnn.profile(CELL["config"], TRAFFIC)
     for key in ("rho", "bwd", "psi", "chi", "delta"):
         np.testing.assert_array_equal(prof[key], getattr(opt.profile, key))
     for cut in (1, 4, 16):
@@ -49,7 +50,7 @@ def test_profile_and_round_times_match_the_program():
 
 def test_theta_matches_the_program_where_it_is_finite():
     fleet, opt = _program(PAPER_LR)
-    prof = CTL.profile(CELL["config"])
+    prof = cnn.profile(CELL["config"], TRAFFIC)
     d = opt.solve(max_iter=4)
     got = CTL.theta(prof, fleet, CTL_CONSTS, _conv(PAPER_LR), d.b, d.cuts)
     assert np.isfinite(got) and got == pytest.approx(d.theta, rel=1e-12)
@@ -61,7 +62,7 @@ def test_theta_matches_the_program_where_it_is_finite():
 
 def test_theta_is_infinite_without_a_corollary_1_solution():
     # at lr 0.05 the drift term alone passes epsilon
-    prof = CTL.profile(CELL["config"])
+    prof = cnn.profile(CELL["config"], TRAFFIC)
     fleet = harness.make_fleet(TRAFFIC, 1)
     b, cuts = np.full(20, 16), np.full(20, 4)
     assert CTL.theta(prof, fleet, CTL_CONSTS, _conv(0.05), b, cuts) == np.inf
@@ -69,7 +70,7 @@ def test_theta_is_infinite_without_a_corollary_1_solution():
 
 
 def test_clock_walks_rounds_and_aggregations():
-    prof = CTL.profile(CELL["config"])
+    prof = cnn.profile(CELL["config"], TRAFFIC)
     fleet = harness.make_fleet(TRAFFIC, 1)
     b, cuts = np.full(20, 16), np.full(20, 4)
     ts, ta = CTL.round_times(prof, fleet, CTL_CONSTS, b, cuts)

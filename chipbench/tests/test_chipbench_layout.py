@@ -88,7 +88,6 @@ def test_per_layer_metrics_name_what_they_move():
 def test_config_files_count_as_the_program_builds():
     import jax
 
-    from chipbench.counts import cnn
     from repro.config import get_config
     from repro.models import build_model
 
@@ -99,7 +98,7 @@ def test_config_files_count_as_the_program_builds():
         model = build_model(get_config(cfg["arch"]))
         shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
         n = sum(x.size for x in jax.tree_util.tree_leaves(shapes))
-        assert n == cfg["params"] == cnn.param_count(cfg)
+        assert n == cfg["params"] == cells.family(cfg).counts.param_count(cfg)
 
 
 def test_peaks_refuse_an_unknown_device():

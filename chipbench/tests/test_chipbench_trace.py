@@ -7,6 +7,7 @@ import pytest
 from chipbench_tiny import ROOT  # noqa: F401  (puts the checkout on the path)
 
 from chipbench import cells
+from chipbench import program_trace as PT
 from chipbench import trace as TR
 from chipbench.counts import cnn
 from chipbench.harness import Context
@@ -65,13 +66,27 @@ def test_op_time():
         pytest.approx(1.5)
 
 
+def _program(tr):
+    """The synthetic trace by the program's scopes: chip 1's
+    ``clip_sgd.7`` (0.1 s) under ``update``, every other op under
+    none."""
+    ops = {chip: [PT.ScopedOp(o.name, o.start, o.end, o.category,
+                              "jit(_scan_segment)/while/body/update/"
+                              "jit(clip_sgd)/pallas_call"
+                              if o.name == "clip_sgd.7" else "")
+                  for o in chip_ops]
+           for chip, chip_ops in tr.ops.items()}
+    return PT.ProgramTrace(ops, {}, [], tr.window())
+
+
 def _ctx(tr, **kw):
-    cfg = {"image_size": 4, "in_channels": 1, "conv_channels": [1],
-           "pool_after": [], "fc_dims": [], "n_classes": 2,
-           "residual": False}
+    cfg = {"reference": "cnn", "image_size": 4, "in_channels": 1,
+           "conv_channels": [1], "pool_after": [], "fc_dims": [],
+           "n_classes": 2, "residual": False}
     import numpy as np
 
-    base = dict(trace=tr, summary=TR.summary(tr), cfg=cfg,
+    base = dict(trace=tr, summary=TR.summary(tr), program=_program(tr),
+                cfg=cfg,
                 traffic={"trace_rounds": 10, "eval_every": 5, "n_test": 4,
                          "fleet": {"n": 2}},
                 b=np.array([2, 2]), peaks=cells.peaks("TPU v5 lite"))
@@ -86,14 +101,15 @@ def test_readers_on_the_synthetic_trace():
     assert idle == pytest.approx(100 * (1 - 2.8 / 5.5))
     roof = cells.reader("conv_roofline")(ctx)
     assert 0 < roof < 1e-3
-    # 12 bytes per parameter per client per round, over 0.1 s of kernel
+    # 12 bytes per parameter per client per round, over the 0.1 s of
+    # device time under the update scope
     least = 10 * 12 * 2 * cnn.param_count(ctx.cfg) / 819e9
     assert cells.reader("clip_sgd_roofline")(ctx) == pytest.approx(
         100 * least / 0.1)
 
 
 def test_readers_read_nothing_without_a_trace():
-    ctx = Context(trace=None, summary=None, peaks=None)
+    ctx = Context(trace=None, summary=None, program=None, peaks=None)
     for m in ("device.idle_share", "step_mfu", "conv_roofline",
               "clip_sgd_roofline"):
         assert cells.reader(m)(ctx) is None

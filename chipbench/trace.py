@@ -93,15 +93,20 @@ def parse(events: List[Dict], op_line: str = "XLA Ops") -> Trace:
     return Trace(dict(ops), spans)
 
 
-def load(trace_dir: str) -> Trace:
-    """Read the newest ``*.trace.json.gz`` under ``trace_dir``."""
+def events(trace_dir: str) -> List[Dict]:
+    """The events of the newest ``*.trace.json.gz`` under ``trace_dir``."""
     files = sorted(glob.glob(os.path.join(
         trace_dir, "plugins", "profile", "*", "*.trace.json.gz")),
         key=os.path.getmtime)
     if not files:
         raise FileNotFoundError(f"no .trace.json.gz under {trace_dir}")
     with gzip.open(files[-1], "rt") as f:
-        return parse(json.load(f)["traceEvents"])
+        return json.load(f)["traceEvents"]
+
+
+def load(trace_dir: str) -> Trace:
+    """Read the newest ``*.trace.json.gz`` under ``trace_dir``."""
+    return parse(events(trace_dir))
 
 
 def clip(intervals: Iterable[Interval], lo: float, hi: float) -> List[Interval]:
