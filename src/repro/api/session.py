@@ -34,6 +34,7 @@ from repro.core.profiles import model_profile
 from repro.core.sfl import SFLEdgeSimulator, SimResult, pow2_bucket
 from repro.data import (
     ClientSampler,
+    make_asr_data,
     make_cifar_like,
     make_lm_data,
     partition_iid,
@@ -175,6 +176,8 @@ class Session:
                 "token architectures use synthetic LM data with no class "
                 "labels; only partition='iid' is supported"
             )
+        if self.cfg.is_enc_dec:
+            return self._asr_data(spec)
         tokens, labels = make_lm_data(
             self.cfg.vocab_size,
             spec.n_train + spec.n_test,
@@ -189,6 +192,19 @@ class Session:
             "tokens": tokens[spec.n_train :],
             "labels": labels[spec.n_train :],
         }
+        return train, test, None
+
+    def _asr_data(self, spec: ExperimentSpec):
+        """Audio cells: 30-s utterances as log-mel features (two frames
+        per encoder position) and transcripts of ``seq_len`` tokens."""
+        n = spec.n_train
+        feats, seqs = make_asr_data(
+            n + spec.n_test, 2 * self.cfg.encoder_seq, self.cfg.n_mels,
+            self.cfg.vocab_size, spec.seq_len, seed=spec.seed)
+        train = {"features": feats[:n], "tokens": seqs[:n, :-1],
+                 "labels": seqs[:n, 1:]}
+        test = {"features": feats[n:], "tokens": seqs[n:, :-1],
+                "labels": seqs[n:, 1:]}
         return train, test, None
 
     # -- conveniences -------------------------------------------------------

@@ -59,7 +59,9 @@ class ModelConfig:
     ssm_expand: int = 2                  # mamba expansion factor
     # --- encoder-decoder (audio) -------------------------------------------
     n_encoder_layers: int = 0            # >0 -> enc-dec model
-    encoder_seq: int = 1500              # frontend-stub frames (whisper 30s)
+    encoder_seq: int = 1500              # encoder positions (whisper 30s)
+    n_mels: int = 80                     # log-mel bins the conv stem reads
+    max_target_positions: int = 448      # learned decoder positions
     # --- VLM ---------------------------------------------------------------
     n_patches: int = 0                   # >0 -> vision-stub patch embeddings
     # --- CNN (paper-faithful CIFAR models) ---------------------------------
@@ -94,19 +96,22 @@ class ModelConfig:
     def n_cut_points(self) -> int:
         """Number of valid cut layers for model splitting.
 
-        For enc-dec models cut points span encoder then decoder blocks.
+        For enc-dec models: one per encoder layer (the conv stem rides
+        the first), then the whole decoder.
         """
         if self.is_cnn:
             # conv layers + fc layers + classifier head (all cuttable)
             return len(self.conv_channels) + len(self.fc_dims) + 1
         if self.is_enc_dec:
-            return self.n_encoder_layers + self.n_layers
+            return self.n_encoder_layers + 1
         return self.n_layers
 
     def param_count(self) -> int:
         """Analytic total parameter count (embedding + blocks + head)."""
         if self.is_cnn:
             return _cnn_param_count(self)
+        if self.is_enc_dec:
+            return sum(whisper_unit_params(self))
         d, hd = self.d_model, self.resolved_head_dim
         n_q, n_kv = self.n_heads, self.n_kv_heads
         attn = d * hd * n_q + 2 * d * hd * n_kv + hd * n_q * d
@@ -134,13 +139,7 @@ class ModelConfig:
                 blocks = self.n_layers * attn + ffns + 2 * d * self.n_layers
         emb = self.vocab_size * d
         head = 0 if self.tie_embeddings else self.vocab_size * d
-        enc = 0
-        if self.is_enc_dec:
-            # encoder blocks: self-attn + MLP; decoder adds cross-attn
-            enc_block = attn + 2 * d * self.d_ff + 2 * d
-            enc = self.n_encoder_layers * enc_block
-            blocks += self.n_layers * attn  # cross attention in decoder
-        return emb + blocks + head + enc
+        return emb + blocks + head
 
     def active_param_count(self) -> int:
         """Params active per token (MoE: only top-k experts count)."""
@@ -152,6 +151,22 @@ class ModelConfig:
         all_experts = n_moe * self.n_experts * 3 * d * self.resolved_d_ff_expert
         active = n_moe * self.top_k * 3 * d * self.resolved_d_ff_expert
         return full - all_experts + active
+
+
+def whisper_unit_params(cfg: ModelConfig) -> list:
+    """Parameters of each `models.whisper` unit: the conv stem, each
+    encoder layer (self-attention with biases on q/v/out, GELU MLP with
+    biases, two LayerNorms), and the decoder (its layers with self and
+    cross attention, MLP and three LayerNorms; the embedding, which is
+    also the head; the learned positions; the encoder's and the
+    decoder's final LayerNorms)."""
+    d, ff = cfg.d_model, cfg.d_ff
+    attn = 4 * d * d + 3 * d
+    mlp = 2 * d * ff + ff + d
+    stem = 3 * cfg.n_mels * d + d + 3 * d * d + d
+    dec = (cfg.n_layers * (2 * attn + mlp + 6 * d)
+           + (cfg.vocab_size + cfg.max_target_positions) * d + 4 * d)
+    return [stem] + [attn + mlp + 4 * d] * cfg.n_encoder_layers + [dec]
 
 
 def _mamba_mixer_params(cfg: ModelConfig) -> int:
@@ -341,7 +356,8 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
                     d_ff_expert=min(cfg.resolved_d_ff_expert, 256),
                     capacity_factor=8.0)
     if cfg.is_enc_dec:
-        base.update(n_encoder_layers=2, encoder_seq=16)
+        base.update(n_encoder_layers=2, encoder_seq=16, n_mels=16,
+                    max_target_positions=64)
     if cfg.n_patches:
         base.update(n_patches=8)
     if cfg.attn_every:

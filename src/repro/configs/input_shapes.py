@@ -42,16 +42,12 @@ def input_specs(cfg: ModelConfig, shape: InputShape) -> dict:
         specs["tokens"] = tok(b, 1)
         specs["positions"] = jax.ShapeDtypeStruct((b,), i32)
 
-    # Modality-frontend stubs (assignment carve-out).
-    if cfg.is_enc_dec:
-        # precomputed audio frame embeddings (mel+conv stub output)
-        enc_s = cfg.encoder_seq
-        if shape.kind == "decode":
-            # encoder ran at prefill; decode consumes cached cross-KV only
-            pass
-        else:
-            specs["frame_embeddings"] = jax.ShapeDtypeStruct(
-                (b, enc_s, cfg.d_model), emb_dtype)
+    if cfg.is_enc_dec and shape.kind != "decode":
+        # log-mel features, two frames per encoder position (decode
+        # consumes the cross K/V that prefill cached)
+        specs["features"] = jax.ShapeDtypeStruct(
+            (b, 2 * cfg.encoder_seq, cfg.n_mels), jnp.float32)
+    # Modality-frontend stub (assignment carve-out).
     if cfg.n_patches and shape.kind != "decode":
         specs["patch_embeddings"] = jax.ShapeDtypeStruct(
             (b, cfg.n_patches, cfg.d_model), emb_dtype)

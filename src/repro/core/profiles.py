@@ -21,7 +21,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
-from repro.config import ModelConfig, CNN
+from repro.config import CNN, ModelConfig, whisper_unit_params
 from repro.models.transformer import layer_program
 
 
@@ -80,21 +80,13 @@ def _transformer_layer_flops(cfg: ModelConfig, kinds: tuple, seq: int) -> float:
     d, hd = cfg.d_model, cfg.resolved_head_dim
     f = 0.0
     for kind in kinds:
-        if kind in ("attn", "attn_nc"):
+        if kind == "attn":
             proj = 2 * seq * d * hd * (cfg.n_heads * 2 + cfg.n_kv_heads * 2)
-            causal = 0.5 if (kind == "attn" and cfg.causal) else 1.0
+            causal = 0.5 if cfg.causal else 1.0
             scores = 2 * seq * seq * cfg.n_heads * hd * 2 * causal
             f += proj + scores
-        elif kind == "xattn":
-            proj = (
-                2 * seq * d * hd * cfg.n_heads * 2
-                + 2 * cfg.encoder_seq * d * hd * cfg.n_kv_heads * 2
-            )
-            f += proj + 2 * seq * cfg.encoder_seq * cfg.n_heads * hd * 2
         elif kind == "ffn":
             f += 2 * seq * 3 * d * cfg.d_ff
-        elif kind == "ffn_gelu":
-            f += 2 * seq * 2 * d * cfg.d_ff
         elif kind == "moe":
             f += 2 * seq * 3 * d * cfg.resolved_d_ff_expert * cfg.top_k
             f += 2 * seq * d * cfg.n_experts          # router
@@ -118,12 +110,10 @@ def _transformer_layer_params(cfg: ModelConfig, kinds: tuple) -> float:
     d, hd = cfg.d_model, cfg.resolved_head_dim
     p = 0.0
     for kind in kinds:
-        if kind in ("attn", "attn_nc", "xattn"):
+        if kind == "attn":
             p += d * hd * (cfg.n_heads * 2 + cfg.n_kv_heads * 2)
         elif kind == "ffn":
             p += 3 * d * cfg.d_ff
-        elif kind == "ffn_gelu":
-            p += 2 * d * cfg.d_ff
         elif kind == "moe":
             p += 3 * d * cfg.resolved_d_ff_expert * cfg.n_experts + d * cfg.n_experts
         elif kind == "mamba":
@@ -147,29 +137,19 @@ def model_profile(
     """Build the per-cut-point profile the HASFL optimizer consumes."""
     if cfg.family == CNN:
         return _cnn_profile(cfg, act_bytes, param_bytes)
+    if cfg.is_enc_dec:
+        return _whisper_profile(cfg, seq_len, act_bytes, param_bytes)
 
     program, repeats = layer_program(cfg)
-    layers = []
-    if cfg.is_enc_dec:
-        enc_prog, enc_reps = 1 * [("attn_nc", "ffn_gelu")], cfg.n_encoder_layers
-        for _ in range(enc_reps):
-            layers.append(("enc", enc_prog[0]))
-    for _ in range(repeats):
-        for kinds in program:
-            layers.append(("dec", kinds))
-
+    layers = [kinds for _ in range(repeats) for kinds in program]
     n = len(layers)
     flops = np.zeros(n)
     params = np.zeros(n)
     psi = np.zeros(n)
-    for idx, (side, kinds) in enumerate(layers):
-        seq = cfg.encoder_seq if side == "enc" else seq_len
-        flops[idx] = _transformer_layer_flops(cfg, kinds, seq)
+    for idx, kinds in enumerate(layers):
+        flops[idx] = _transformer_layer_flops(cfg, kinds, seq_len)
         params[idx] = _transformer_layer_params(cfg, kinds)
-        psi[idx] = _act_bits(cfg, seq, act_bytes)
-        if side == "enc" and idx == cfg.n_encoder_layers - 1:
-            # cutting at the enc/dec boundary ships encoder output once
-            psi[idx] = _act_bits(cfg, cfg.encoder_seq, act_bytes)
+        psi[idx] = _act_bits(cfg, seq_len, act_bytes)
 
     # embedding params on the first layer; head on the last
     params[0] += cfg.vocab_size * cfg.d_model
@@ -184,6 +164,35 @@ def model_profile(
     return LayerProfile(
         rho=rho, bwd=bwd, psi=psi, chi=psi.copy(), delta=delta, params=params,
         g_sq=g_sq, sigma_sq=sigma_sq)
+
+
+def _whisper_profile(cfg: ModelConfig, seq_len: int, act_bytes: int,
+                     param_bytes: int) -> LayerProfile:
+    """`models.whisper`'s units: cut point ``j`` (1..L) puts the conv
+    stem and encoder layers 1..j on the client, and ships the encoder's
+    ``encoder_seq x d`` activations; the last point is the decoder (with
+    its embeddings, positions, head and LayerNorms), always server-side.
+    Operations count every multiply-add of the projections, the MLPs,
+    both convolutions (every tap) and the attention cores (causal ones
+    in full); the encoder's cross-attention K/V projections belong to the
+    decoder, whose weights they are."""
+    d, ff, se, s = cfg.d_model, cfg.d_ff, cfg.encoder_seq, seq_len
+    stem = 2 * 3 * d * (cfg.n_mels * 2 * se + d * se)
+    enc = 8 * se * d * d + 4 * se * d * ff + 4 * se * se * d
+    dec_layer = (8 * s * d * d + 4 * s * s * d                 # self
+                 + 4 * s * d * d + 4 * se * d * d + 4 * s * se * d  # cross
+                 + 4 * s * d * ff)
+    dec = cfg.n_layers * dec_layer + 2 * s * d * cfg.vocab_size
+    n_enc = cfg.n_encoder_layers
+    flops = np.asarray([stem + enc] + [enc] * (n_enc - 1) + [dec], float)
+    units = whisper_unit_params(cfg)         # the stem rides cut point 1
+    params = np.asarray([units[0] + units[1]] + units[2:], float)
+    psi = np.asarray([se * d] * n_enc + [s * d], float) * 8 * act_bytes
+    g_sq, sigma_sq = _assumption2_priors(params)
+    return LayerProfile(
+        rho=np.cumsum(flops), bwd=np.cumsum(flops * BWD_MULT), psi=psi,
+        chi=psi.copy(), delta=np.cumsum(params) * 8.0 * param_bytes,
+        params=params, g_sq=g_sq, sigma_sq=sigma_sq)
 
 
 def _cnn_profile(cfg: ModelConfig, act_bytes: int, param_bytes: int) -> LayerProfile:

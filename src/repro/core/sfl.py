@@ -696,6 +696,17 @@ class SFLEdgeSimulator:
                 f"acc {ta:.4f}", flush=True
             )
 
+    def _dispatch_counts(self, b, b_pad: int, rounds: int) -> dict:
+        """Counters of a segment's ``repro.dispatch`` span: ``rows`` (real
+        rows), ``padded_rows`` (rows the executable computes) and, for an
+        encoder-decoder, ``frames`` (the real rows' encoder positions),
+        summed over the segment's rounds."""
+        rows = int(np.sum(b)) * rounds
+        out = {"rows": rows, "padded_rows": self.n * b_pad * rounds}
+        if self.cfg.is_enc_dec:
+            out["frames"] = rows * self.cfg.encoder_seq
+        return out
+
     def _segment_participation(self, t: int, nxt: int, b, cuts, scenario):
         """Pre-compute the ``[R, N]`` participation plan for rounds
         (t, nxt] by walking each round's trace state host-side (the same
@@ -769,9 +780,8 @@ class SFLEdgeSimulator:
                     row_mask = self.store.row_mask(b, b_pad)
                     parts = self._segment_participation(
                         t, nxt, b, cuts, scenario)
-                with span("dispatch", t=t0,
-                          rows=int(np.sum(b)) * (nxt - t),
-                          padded_rows=self.n * b_pad * (nxt - t)):
+                with span("dispatch", t=t0, **self._dispatch_counts(
+                        b, b_pad, nxt - t)):
                     self._stacked, seg_losses = self._scan_fn(
                         self._stacked, jnp.asarray(t, jnp.int32), idx,
                         row_mask, masks, self.store.arrays, parts)

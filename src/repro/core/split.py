@@ -7,6 +7,8 @@ Two granularities:
   CNNs: one unit per conv/fc layer (exactly the paper's VGG-16 splitting).
   Transformers: one unit per super-block repetition, plus the embedding
   (always client-side — it touches raw data) and the head (always server).
+  Whisper: the conv stem (always client-side — it touches the raw
+  audio), one unit per encoder layer, and the decoder (always server).
 
 - **stacked split** (SPMD pod path): the first ``c`` repetitions of the
   scan-stacked decoder are re-stacked per-client ``[N, c, ...]``; the rest
@@ -20,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.config import ModelConfig, CNN
+from repro.config import ModelConfig, AUDIO, CNN
 from repro.models.transformer import layer_program, stack_params, unstack_params
 
 
@@ -30,7 +32,7 @@ from repro.models.transformer import layer_program, stack_params, unstack_params
 
 def to_units(cfg: ModelConfig, params) -> Tuple[list, Callable]:
     """Returns (units, rebuild) where rebuild(units) -> params."""
-    if cfg.family == CNN:
+    if cfg.family in (CNN, AUDIO):
         units = list(params)
         return units, lambda us: list(us)
     program, repeats = layer_program(cfg)
@@ -38,9 +40,6 @@ def to_units(cfg: ModelConfig, params) -> Tuple[list, Callable]:
     head_unit = {"final_norm": params["final_norm"]}
     if "head" in params:
         head_unit["head"] = params["head"]
-    if cfg.is_enc_dec:
-        head_unit["enc_stack"] = params["enc_stack"]
-        head_unit["enc_final_norm"] = params["enc_final_norm"]
     units = [{"embed": params["embed"]}] + reps + [head_unit]
 
     def rebuild(us):
@@ -51,9 +50,6 @@ def to_units(cfg: ModelConfig, params) -> Tuple[list, Callable]:
         }
         if "head" in us[-1]:
             out["head"] = us[-1]["head"]
-        if "enc_stack" in us[-1]:
-            out["enc_stack"] = us[-1]["enc_stack"]
-            out["enc_final_norm"] = us[-1]["enc_final_norm"]
         return out
 
     return units, rebuild
@@ -63,13 +59,15 @@ def n_cut_units(cfg: ModelConfig, units: list) -> int:
     """Number of valid cut positions in unit space."""
     if cfg.family == CNN:
         return len(units)           # cut after any layer
-    return len(units) - 2           # embed fixed client, head fixed server
+    return len(units) - 2           # embed/stem client, head/decoder server
 
 
 def layer_cut_to_unit_cut(cfg: ModelConfig, cut_layer: int) -> int:
     """Map a profile-granularity cut (1..L) to unit granularity."""
     if cfg.family == CNN:
         return cut_layer
+    if cfg.family == AUDIO:         # the decoder's point is not a cut
+        return min(cfg.n_encoder_layers, max(1, cut_layer))
     program, repeats = layer_program(cfg)
     period = len(program)
     return min(repeats, max(1, -(-cut_layer // period)))
@@ -79,7 +77,8 @@ def split_units(units: list, cut_units: int, cfg: ModelConfig):
     """Client keeps units [0, k); server keeps the rest.
 
     For transformers k counts *repetitions*, so the client side is
-    ``units[0 .. cut_units]`` (embedding + cut_units repetitions).
+    ``units[0 .. cut_units]`` (embedding + cut_units repetitions); for
+    Whisper, encoder layers after the stem.
     """
     k = cut_units if cfg.family == CNN else cut_units + 1
     return units[:k], units[k:]
@@ -135,7 +134,8 @@ def client_unit_mask(cfg: ModelConfig, n_units: int, l_c_units: int):
     """1.0 for client-specific (every-I) units, 0.0 for server-common.
 
     CNNs: the first ``l_c_units`` layers.  Transformers: the embedding plus
-    the first ``l_c_units`` repetitions (the head unit is always server).
+    the first ``l_c_units`` repetitions (the head unit is always server);
+    Whisper: the stem plus the first ``l_c_units`` encoder layers.
     """
     mask = np.zeros((n_units,), np.float32)
     if cfg.family == CNN:
@@ -163,8 +163,10 @@ def two_tier_common(spec, w, edge_size, axis_name):
     w_col = w.reshape((-1,) + (1,) * (spec.ndim - 1))
     edge_sums = (spec * w_col).reshape(
         (n_local // e, e) + spec.shape[1:]).sum(axis=1)
-    total = jax.lax.psum(edge_sums.sum(axis=0), axis_name)
-    cnt = jax.lax.psum(w.sum(), axis_name)
+    local = edge_sums.sum(axis=0)
+    with jax.named_scope("psum"):
+        total = jax.lax.psum(local, axis_name)
+        cnt = jax.lax.psum(w.sum(), axis_name)
     return total / jnp.where(cnt > 0, cnt, 1.0), cnt
 
 

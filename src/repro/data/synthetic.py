@@ -8,6 +8,9 @@ container (documented substitution, DESIGN.md §7).
 `make_lm_data`: token sequences from a sparse random bigram/skip-gram
 process — a language-model dataset with real structure so LM training loss
 decreases.
+
+`make_asr_data`: log-mel features and transcripts for the audio
+encoder-decoder, the features made from the transcripts.
 """
 from __future__ import annotations
 
@@ -42,22 +45,45 @@ def make_cifar_like(n_classes: int = 10, n_train: int = 2000,
     return (xtr, ytr), (xte, yte)
 
 
-def make_lm_data(vocab: int = 512, n_seqs: int = 512, seq_len: int = 128,
-                 seed: int = 0):
-    """Structured token stream: a random sparse Markov chain."""
-    rng = np.random.default_rng(seed)
-    # each token has a small successor set -> learnable transitions
+def markov_tokens(rng: np.random.Generator, vocab: int, n_seqs: int,
+                  length: int) -> np.ndarray:
+    """``[n_seqs, length]`` tokens of a random sparse Markov chain: each
+    token has four successors, with a random jump 5% of the time."""
     n_succ = 4
     successors = rng.integers(0, vocab, (vocab, n_succ))
-    seqs = np.zeros((n_seqs, seq_len + 1), np.int32)
+    seqs = np.zeros((n_seqs, length), np.int32)
     state = rng.integers(0, vocab, n_seqs)
-    for t in range(seq_len + 1):
+    for t in range(length):
         seqs[:, t] = state
         pick = rng.integers(0, n_succ, n_seqs)
         state = successors[state, pick]
-        # occasional random jump for entropy
         jump = rng.random(n_seqs) < 0.05
         state = np.where(jump, rng.integers(0, vocab, n_seqs), state)
-    tokens = seqs[:, :-1]
-    labels = seqs[:, 1:]
-    return tokens, labels
+    return seqs
+
+
+def make_lm_data(vocab: int = 512, n_seqs: int = 512, seq_len: int = 128,
+                 seed: int = 0):
+    """Structured token stream: a random sparse Markov chain."""
+    seqs = markov_tokens(np.random.default_rng(seed), vocab, n_seqs,
+                         seq_len + 1)
+    return seqs[:, :-1], seqs[:, 1:]
+
+
+def make_asr_data(n: int, frames: int, n_mels: int, vocab: int,
+                  seq_len: int, seed: int = 0):
+    """Synthetic utterances: ``(features [n, frames, n_mels] float32,
+    transcripts [n, seq_len + 1] int32)``.
+
+    Transcripts are `markov_tokens`; each frame's log-mel features are
+    the template of the token spoken at that time (the transcript spread
+    evenly over the frames), half and half with unit noise, so the
+    decoder can learn to read the audio."""
+    rng = np.random.default_rng(seed)
+    seqs = markov_tokens(rng, vocab, n, seq_len + 1)
+    templates = rng.standard_normal((vocab, n_mels), dtype=np.float32)
+    at = np.arange(frames) * (seq_len + 1) // frames
+    feats = templates[seqs[:, at]]
+    feats += rng.standard_normal(feats.shape, dtype=np.float32)
+    feats *= np.float32(0.5)
+    return feats, seqs

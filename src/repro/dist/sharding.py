@@ -34,7 +34,7 @@ from repro.launch.mesh import axis_size, dp_axes
 # Leaf names holding per-expert weights (stacked [R, E, ...]).
 EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
 # Tree keys under which leaves carry a leading lax.scan stack axis.
-STACK_KEYS = ("stack", "stack_prefix", "stack_suffix", "enc_stack")
+STACK_KEYS = ("stack", "stack_prefix", "stack_suffix")
 # Tree keys under which leaves carry a leading per-client N axis.
 CLIENT_KEYS = ("client", "client_units")
 

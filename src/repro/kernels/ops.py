@@ -2,7 +2,7 @@
 
 On a real TPU (``jax.default_backend() == 'tpu'``) the compiled kernels
 run natively; elsewhere they run in interpret mode (CPU validation) or
-fall back to a jnp formulation.  All five wrappers resolve their
+fall back to a jnp formulation.  All six wrappers resolve their
 ``impl`` through one `dispatch` helper:
 
 - ``"auto"``  — native kernel on TPU; off-TPU the *fallback* (the jnp
@@ -24,6 +24,8 @@ from repro.kernels import batched_conv as BC
 from repro.kernels import ref as REF
 from repro.kernels.clip_sgd import clip_sgd_update as _clip_sgd
 from repro.kernels.flash_attention import flash_attention as _flash
+from repro.kernels.flash_attention import \
+    flash_attention_trainable as _flash_trainable
 from repro.kernels.mlstm_scan import mlstm_scan as _mlstm
 from repro.kernels.rmsnorm import rmsnorm as _rmsnorm
 
@@ -64,6 +66,20 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         ref=functools.partial(REF.flash_attention_ref, causal=causal,
                               window=window),
         kernel=functools.partial(_flash, causal=causal, window=window))
+    return fn(q, k, v)
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "impl"))
+def flash_attention_trainable(q, k, v, *, causal: bool = False,
+                              impl: str = "auto"):
+    """Differentiable flash attention, equal head counts.
+
+    impl: auto | kernel | interpret | ref.  ``ref`` (and ``auto``
+    off-TPU) is plain attention under autodiff."""
+    fn = dispatch(
+        impl, ref=functools.partial(REF.flash_attention_ref, causal=causal),
+        kernel=lambda q, k, v, interpret: _flash_trainable(
+            q, k, v, causal, 512, interpret))
     return fn(q, k, v)
 
 

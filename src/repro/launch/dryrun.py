@@ -38,9 +38,12 @@ FORCE_ACCUM_SCALE = 1.0
 
 # (arch, shape) combos that are skipped, with the DESIGN.md reason.
 SKIPS = {
-    ("whisper-medium", "long_500k"):
-        "enc-dec audio model: 500k-token decode is architecturally "
-        "meaningless (30s windows, 448-token decoder context); see DESIGN.md",
+    ("whisper-medium", shape):
+        "enc-dec audio model: its parameters are the edge simulator's unit "
+        "list (conv stem, encoder layers, decoder; models/whisper.py), which "
+        "the stacked SPMD split does not cut; and a 500k-token decode is "
+        "meaningless for it (30s windows, 448-token decoder context)"
+    for shape in INPUT_SHAPES
 }
 
 

@@ -1,6 +1,8 @@
 """Public model API: build_model(cfg) -> Model bundle.
 
-One entry point for all 12 architectures (10 assigned + 2 paper CNNs).
+One entry point for all 12 architectures (10 assigned + 2 paper CNNs):
+CNNs (`models.cnn`), Whisper (`models.whisper`) and the token families
+(`models.transformer`).
 """
 from __future__ import annotations
 
@@ -10,10 +12,11 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro.config import ModelConfig, VLM, CNN
+from repro.config import AUDIO, CNN, VLM, ModelConfig
 from repro.models import layers as L
 from repro.models import transformer as T
 from repro.models import cnn as C
+from repro.models import whisper as W
 
 
 @dataclass
@@ -43,6 +46,8 @@ def _merge_patches(x, patch_embeddings, patch_mask):
 def build_model(cfg: ModelConfig) -> Model:
     if cfg.family == CNN:
         return _build_cnn(cfg)
+    if cfg.family == AUDIO:
+        return _build_whisper(cfg)
     return _build_transformer(cfg)
 
 
@@ -55,7 +60,8 @@ def _build_transformer(cfg: ModelConfig) -> Model:
     dtype = jnp.dtype(cfg.dtype)
 
     def init(rng):
-        r_emb, r_stack, r_head, r_enc = jax.random.split(rng, 4)
+        # four keys, the fourth unused: the split fixes every init
+        r_emb, r_stack, r_head, _ = jax.random.split(rng, 4)
         params = {
             "embed": L.embed_init(r_emb, cfg.vocab_size, cfg.d_model, dtype),
             "stack": T.stack_init(r_stack, cfg, program, repeats),
@@ -64,20 +70,7 @@ def _build_transformer(cfg: ModelConfig) -> Model:
         if not cfg.tie_embeddings:
             params["head"] = L.dense_init(r_head, cfg.d_model, cfg.vocab_size,
                                           dtype)
-        if cfg.is_enc_dec:
-            enc_prog, enc_reps = T.encoder_program(cfg)
-            params["enc_stack"] = T.stack_init(r_enc, cfg, enc_prog, enc_reps)
-            params["enc_final_norm"] = jnp.ones((cfg.d_model,), jnp.float32)
         return params
-
-    def _encode(params, frame_embeddings, shard_fn=None):
-        enc_prog, _ = T.encoder_program(cfg)
-        s = frame_embeddings.shape[1]
-        pos_table = jnp.asarray(L.sinusoidal_positions(s, cfg.d_model), dtype)
-        x = frame_embeddings.astype(dtype) + pos_table[None]
-        ctx = {"positions": jnp.arange(s)[None, :], "shard_fn": shard_fn}
-        x, _ = T.stack_fwd(params["enc_stack"], x, cfg, enc_prog, ctx)
-        return L.rmsnorm(x, params["enc_final_norm"], cfg.norm_eps)
 
     def _embed_inputs(params, batch):
         tokens = batch["tokens"]
@@ -85,10 +78,6 @@ def _build_transformer(cfg: ModelConfig) -> Model:
         if cfg.family == VLM and "patch_embeddings" in batch:
             x = _merge_patches(x, batch["patch_embeddings"],
                                batch["patch_mask"])
-        if cfg.is_enc_dec and cfg.rope_theta <= 0:
-            s = tokens.shape[1]
-            x = x + jnp.asarray(L.sinusoidal_positions(s, cfg.d_model),
-                                dtype)[None]
         return x
 
     def _logits(params, x):
@@ -104,9 +93,6 @@ def _build_transformer(cfg: ModelConfig) -> Model:
                "rep_shard_fn": rep_shard_fn}
         if window is not None:
             ctx["window"] = window
-        if cfg.is_enc_dec:
-            ctx["enc_out"] = _encode(params, batch["frame_embeddings"],
-                                     shard_fn)
         x, aux = T.stack_fwd(params["stack"], x, cfg, program, ctx,
                              remat=remat, unroll=unroll)
         return _logits(params, x), {"lb_loss": aux}
@@ -119,9 +105,6 @@ def _build_transformer(cfg: ModelConfig) -> Model:
                "rep_shard_fn": rep_shard_fn}
         if window is not None:
             ctx["window"] = window
-        if cfg.is_enc_dec:
-            ctx["enc_out"] = _encode(params, batch["frame_embeddings"],
-                                     shard_fn)
         x, aux = T.stack_fwd(params["stack"], x, cfg, program, ctx,
                              remat=remat, unroll=unroll)
         return L.rmsnorm(x, params["final_norm"], cfg.norm_eps), aux
@@ -198,26 +181,13 @@ def _build_transformer(cfg: ModelConfig) -> Model:
         s = batch["tokens"].shape[2]
         positions = jnp.arange(s)[None, :]
 
-        enc_out = None
-        if cfg.is_enc_dec:
-            fe = batch["frame_embeddings"]
-            fe_m = fe.reshape((-1,) + fe.shape[2:])
-            enc_out = _encode({"enc_stack": server["enc_stack"],
-                               "enc_final_norm": server["enc_final_norm"]},
-                              fe_m, shard_fn)
-
-        def prefix_fwd(client_i, batch_i, enc_i):
+        def prefix_fwd(client_i, batch_i):
             x = client_i["embed"][batch_i["tokens"]]
             if cfg.family == VLM and "patch_embeddings" in batch_i:
                 x = _merge_patches(x, batch_i["patch_embeddings"],
                                    batch_i["patch_mask"])
-            if cfg.is_enc_dec and cfg.rope_theta <= 0:
-                x = x + jnp.asarray(L.sinusoidal_positions(s, cfg.d_model),
-                                    dtype)[None]
             ctx = {"positions": positions, "shard_fn": shard_fn,
                    "rep_shard_fn": rep_shard_fn}
-            if enc_i is not None:
-                ctx["enc_out"] = enc_i
             leaves = jax.tree_util.tree_leaves(client_i["stack_prefix"])
             if leaves and leaves[0].shape[0] > 0:
                 x, aux = T.stack_fwd(client_i["stack_prefix"], x, cfg,
@@ -227,19 +197,11 @@ def _build_transformer(cfg: ModelConfig) -> Model:
                 aux = 0.0
             return x, aux
 
-        enc_per_client = None
-        if enc_out is not None:
-            enc_per_client = enc_out.reshape((n, bsz) + enc_out.shape[1:])
-        xs, aux_c = jax.vmap(
-            prefix_fwd,
-            in_axes=(0, 0, 0 if enc_out is not None else None))(
-            client_stacked, batch, enc_per_client)
+        xs, aux_c = jax.vmap(prefix_fwd)(client_stacked, batch)
         # --- activation hand-off: concatenate the client batch (a2) -----
         x = xs.reshape((n * bsz,) + xs.shape[2:])
         ctx = {"positions": positions, "shard_fn": shard_fn,
                "rep_shard_fn": rep_shard_fn}
-        if enc_out is not None:
-            ctx["enc_out"] = enc_out
         x, aux_s = T.stack_fwd(server["stack_suffix"], x, cfg, program, ctx,
                                remat=remat, unroll=unroll)
         x = L.rmsnorm(x, server["final_norm"], cfg.norm_eps)
@@ -273,9 +235,6 @@ def _build_transformer(cfg: ModelConfig) -> Model:
                "shard_fn": shard_fn}
         if window is not None:
             ctx["window"] = window
-        if cfg.is_enc_dec:
-            ctx["enc_out"] = _encode(params, batch["frame_embeddings"],
-                                     shard_fn)
         x, cache = T.stack_prefill(params["stack"], cache, x, cfg, program,
                                    ctx)
         return _logits(params, x[:, -1:]), cache
@@ -284,10 +243,6 @@ def _build_transformer(cfg: ModelConfig) -> Model:
                     shard_fn=None):
         tokens, positions = batch["tokens"], batch["positions"]
         x = params["embed"][tokens]                 # [B, 1, d]
-        if cfg.is_enc_dec and cfg.rope_theta <= 0:
-            pos_table = jnp.asarray(
-                L.sinusoidal_positions(8192, cfg.d_model), dtype)
-            x = x + pos_table[jnp.clip(positions, 0, 8191)][:, None]
         ctx = {"positions": positions, "unroll": unroll,
                "shard_fn": shard_fn}
         if window is not None:
@@ -299,6 +254,31 @@ def _build_transformer(cfg: ModelConfig) -> Model:
     model = Model(cfg, init, apply, loss, init_cache, prefill, decode_step)
     model.split_loss = split_loss
     return model
+
+
+# ---------------------------------------------------------------------------
+# Whisper
+# ---------------------------------------------------------------------------
+
+def _build_whisper(cfg: ModelConfig) -> Model:
+    """`models.whisper`; its parameters are the simulator's unit list."""
+    def apply(params, batch, shard_fn=None, **kw):
+        return W.apply(params, batch, cfg), {}
+
+    def loss(params, batch, shard_fn=None, **kw):
+        return W.loss(params, batch, cfg)
+
+    def init_cache(batch, cache_len, window=None):
+        return W.init_cache(cfg, batch, cache_len)
+
+    def prefill(params, batch, cache_len=None, **kw):
+        return W.prefill(params, batch, cfg, cache_len)
+
+    def decode_step(params, cache, batch, **kw):
+        return W.decode_step(params, cache, batch, cfg)
+
+    return Model(cfg, lambda rng: W.init(rng, cfg), apply, loss, init_cache,
+                 prefill, decode_step)
 
 
 # ---------------------------------------------------------------------------

@@ -51,16 +51,6 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
-def sinusoidal_positions(seq: int, d: int) -> np.ndarray:
-    pos = np.arange(seq)[:, None]
-    dim = np.arange(0, d, 2)[None, :]
-    ang = pos / (10000 ** (dim / d))
-    out = np.zeros((seq, d), np.float32)
-    out[:, 0::2] = np.sin(ang)
-    out[:, 1::2] = np.cos(ang)
-    return out
-
-
 # --- feed-forward ------------------------------------------------------------
 
 def swiglu_init(rng, d: int, d_ff: int, dtype) -> dict:
@@ -75,16 +65,6 @@ def swiglu_init(rng, d: int, d_ff: int, dtype) -> dict:
 def swiglu(params: dict, x: jax.Array) -> jax.Array:
     g = jax.nn.silu(x @ params["w_gate"])
     return (g * (x @ params["w_up"])) @ params["w_down"]
-
-
-def gelu_mlp_init(rng, d: int, d_ff: int, dtype) -> dict:
-    r1, r2 = jax.random.split(rng)
-    return {"w_up": dense_init(r1, d, d_ff, dtype),
-            "w_down": dense_init(r2, d_ff, d, dtype)}
-
-
-def gelu_mlp(params: dict, x: jax.Array) -> jax.Array:
-    return jax.nn.gelu(x @ params["w_up"]) @ params["w_down"]
 
 
 def chunked_scan(step, carry0, xs, *, chunk: int = 256):

@@ -7,8 +7,9 @@ for 48-layer models, which is what makes the 512-device dry-run compile in
 reasonable time (the MaxText idiom).
 
 Block types: ``attn`` (self, causal or not, GQA + RoPE + qk-norm +
-sliding window), ``xattn`` (cross), ``ffn`` (SwiGLU), ``ffn_gelu``,
-``moe``, ``mamba``, ``mlstm``, ``slstm``.
+sliding window), ``ffn`` (SwiGLU), ``moe``, ``mamba``, ``mlstm``,
+``slstm``.  The audio encoder-decoder has blocks of its own
+(`models.whisper`).
 
 The HASFL split point is a *layer index*; ``unstack/stack`` helpers let
 core/split.py cut the stacked tree at any super-block multiple (and the
@@ -21,7 +22,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.config import ModelConfig, MOE, SSM, HYBRID, AUDIO
+from repro.config import ModelConfig, MOE, SSM, HYBRID
 from repro.models import layers as L
 from repro.models import attention as A
 from repro.models import moe as M
@@ -67,15 +68,8 @@ def layer_program(cfg: ModelConfig) -> tuple:
             sb.append(("attn", ffn))
         return sb, cfg.n_layers // period
 
-    if cfg.family == AUDIO:  # decoder program (encoder handled separately)
-        return [("attn", "xattn", "ffn_gelu")], cfg.n_layers
-
     # dense / vlm
     return [("attn", "ffn")], cfg.n_layers
-
-
-def encoder_program(cfg: ModelConfig) -> tuple:
-    return [("attn_nc", "ffn_gelu")], cfg.n_encoder_layers
 
 
 # ---------------------------------------------------------------------------
@@ -101,14 +95,10 @@ def _attn_init(rng, cfg: ModelConfig, dtype) -> dict:
 def block_init(rng, kind: str, cfg: ModelConfig) -> dict:
     dtype = jnp.dtype(cfg.dtype)
     d = cfg.d_model
-    if kind in ("attn", "attn_nc", "xattn"):
+    if kind == "attn":
         return _attn_init(rng, cfg, dtype)
     if kind == "ffn":
         p = L.swiglu_init(rng, d, cfg.d_ff, dtype)
-        p["norm"] = jnp.ones((d,), jnp.float32)
-        return p
-    if kind == "ffn_gelu":
-        p = L.gelu_mlp_init(rng, d, cfg.d_ff, dtype)
         p["norm"] = jnp.ones((d,), jnp.float32)
         return p
     if kind == "moe":
@@ -149,27 +139,15 @@ def block_fwd(kind: str, p: dict, x: jax.Array, cfg: ModelConfig, ctx: dict):
     """Returns (delta, aux) — caller adds the residual."""
     b, s, d = x.shape
     aux = {}
-    if kind in ("attn", "attn_nc"):
-        causal = kind == "attn" and cfg.causal
+    if kind == "attn":
+        causal = cfg.causal
         q, k, v = _qkv(p, cfg, x, ctx["positions"])
         window = ctx.get("window", cfg.sliding_window)
         o = A.attention(q, k, v, causal=causal, window=window if causal else 0,
                         unroll=ctx.get("unroll", False))
         return o.reshape(b, s, -1) @ p["wo"], aux
-    if kind == "xattn":
-        enc = ctx["enc_out"]                      # [B, Senc, d]
-        hd = cfg.resolved_head_dim
-        xn = L.rmsnorm(x, p["norm"], cfg.norm_eps)
-        q = (xn @ p["wq"]).reshape(b, s, cfg.n_heads, hd)
-        k = (enc @ p["wk"]).reshape(b, enc.shape[1], cfg.n_kv_heads, hd)
-        v = (enc @ p["wv"]).reshape(b, enc.shape[1], cfg.n_kv_heads, hd)
-        o = A.attention(q, k, v, causal=False, window=0,
-                        unroll=ctx.get("unroll", False))
-        return o.reshape(b, s, -1) @ p["wo"], aux
     if kind == "ffn":
         return L.swiglu(p, L.rmsnorm(x, p["norm"], cfg.norm_eps)), aux
-    if kind == "ffn_gelu":
-        return L.gelu_mlp(p, L.rmsnorm(x, p["norm"], cfg.norm_eps)), aux
     if kind == "moe":
         out, aux = M.moe_ffn(p, L.rmsnorm(x, p["norm"], cfg.norm_eps),
                              top_k=cfg.top_k,
@@ -293,12 +271,6 @@ def layer_cache_init(layer: tuple, cfg: ModelConfig, batch: int,
     for bi, kind in enumerate(layer):
         if kind == "attn":
             out[f"b{bi}"] = _attn_cache_init(cfg, batch, eff_len, dtype)
-        elif kind == "xattn":
-            hd = cfg.resolved_head_dim
-            out[f"b{bi}"] = {
-                "k": jnp.zeros((batch, cfg.encoder_seq, cfg.n_kv_heads, hd), dtype),
-                "v": jnp.zeros((batch, cfg.encoder_seq, cfg.n_kv_heads, hd), dtype),
-            }
         elif kind == "mamba":
             d_in = cfg.ssm_expand * cfg.d_model
             out[f"b{bi}"] = MB.mamba_decode_init(batch, d_in, cfg.ssm_state_dim,
@@ -358,13 +330,7 @@ def block_decode(kind: str, p: dict, x: jax.Array, cache, cfg: ModelConfig,
         o = A.decode_attention(q, new_k, new_v, new_pos, pos, window=window)
         return o.reshape(b, 1, -1) @ p["wo"], {"k": new_k, "v": new_v,
                                                "pos": new_pos}
-    if kind == "xattn":
-        hd = cfg.resolved_head_dim
-        xn = L.rmsnorm(x, p["norm"], cfg.norm_eps)
-        q = (xn @ p["wq"]).reshape(b, 1, cfg.n_heads, hd)
-        o = A.attention(q, cache["k"], cache["v"], causal=False, window=0)
-        return o.reshape(b, 1, -1) @ p["wo"], cache
-    if kind in ("ffn", "ffn_gelu", "moe"):
+    if kind in ("ffn", "moe"):
         delta, _ = block_fwd(kind, p, x, cfg, ctx)
         return delta, cache
     if kind == "mamba":
@@ -436,16 +402,6 @@ def stack_prefill(stacked: dict, caches: dict, x: jax.Array, cfg: ModelConfig,
                             jnp.arange(s_ - take, s_)[None, :]),
                     }
                     lc[key] = new_c
-                elif kind == "xattn" and cache_b is not None:
-                    enc = ctx["enc_out"]
-                    hd = cfg.resolved_head_dim
-                    delta, _ = block_fwd(kind, p, x, cfg, ctx)
-                    lc[key] = {
-                        "k": (enc @ p["wk"]).reshape(enc.shape[0], enc.shape[1],
-                                                     cfg.n_kv_heads, hd),
-                        "v": (enc @ p["wv"]).reshape(enc.shape[0], enc.shape[1],
-                                                     cfg.n_kv_heads, hd),
-                    }
                 else:
                     delta, _ = block_fwd(kind, p, x, cfg, ctx)
                     if cache_b is not None:

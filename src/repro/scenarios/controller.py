@@ -28,7 +28,7 @@ from typing import Optional
 import jax
 import numpy as np
 
-from repro.config import CNN, SFLConfig
+from repro.config import AUDIO, CNN, SFLConfig
 from repro.core import baselines
 from repro.core.bcd import HASFLOptimizer
 from repro.core.convergence import estimate_constants
@@ -44,13 +44,16 @@ def unit_layer_spans(cfg, n_layers: int, n_units: int) -> list:
     """Map each simulator *unit* to its span of profile layers.
 
     CNNs are exact 1:1 (one unit per conv/fc layer — the paper's VGG
-    splitting).  Transformers map the embedding unit onto layer 0, each
+    splitting), and so is Whisper past its stem.  Transformers map the embedding unit onto layer 0, each
     super-block repetition onto its ``period`` profile layers, and the
     head unit onto the last layer.  Returns ``[(lo, hi), ...]`` with
     half-open 0-based layer ranges, one per unit.
     """
     if cfg.family == CNN:
         return [(u, u + 1) for u in range(n_units)]
+    if cfg.family == AUDIO:
+        # the stem rides layer 0 with the first encoder layer
+        return [(0, 1)] + [(u - 1, u) for u in range(1, n_units)]
     reps = n_units - 2
     period = max(1, n_layers // max(reps, 1))
     spans = [(0, 1)]  # embed -> layer 0

@@ -15,8 +15,9 @@ Spans the program records (parents first):
 - ``repro.plan``: cut map, unit mask, bucket, gather plan, row mask,
   participation;
 - ``repro.dispatch`` (``rows``: real rows, ``padded_rows``: rows the
-  executable computes, both summed over the segment's rounds): the call
-  of the segment executable;
+  executable computes, both summed over the segment's rounds; for an
+  encoder-decoder in `_run_scan`, ``frames``: the real rows' encoder
+  positions): the call of the segment executable;
 - ``repro.clock``: the simulated clock walk;
 - ``repro.control``: the policy / controller at a reconfiguration;
 - ``repro.fetch``: the wait for the segment's per-round losses;

@@ -48,12 +48,12 @@ def test_prefill_then_decode_whisper():
     b, s = 2, 8
     rng = np.random.default_rng(0)
     toks = rng.integers(0, cfg.vocab_size, (b, s))
-    fe = jnp.asarray(rng.standard_normal((b, cfg.encoder_seq, cfg.d_model)),
-                     jnp.dtype(cfg.dtype))
+    feats = jnp.asarray(rng.standard_normal(
+        (b, 2 * cfg.encoder_seq, cfg.n_mels)), jnp.float32)
     full, _ = model.apply(params, {"tokens": jnp.asarray(toks),
-                                   "frame_embeddings": fe})
+                                   "features": feats})
     lg, cache = model.prefill(params, {"tokens": jnp.asarray(toks[:, :4]),
-                                       "frame_embeddings": fe}, cache_len=s)
+                                       "features": feats}, cache_len=s)
     np.testing.assert_allclose(np.asarray(lg[:, 0], np.float32),
                                np.asarray(full[:, 3], np.float32),
                                rtol=0.07, atol=0.05)
